@@ -17,13 +17,13 @@ def main():
     world = int(sys.argv[1])
     rows = int(sys.argv[2])
     impl = sys.argv[3] if len(sys.argv) > 3 else "sortmerge"
+    from repro.launch.env import enable_compile_cache
+    enable_compile_cache()
     import jax
-    from jax.sharding import Mesh
     from repro.core import dist_ops as D
-    from repro.core.context import make_context
+    from repro.core.context import make_context, make_mesh
 
-    dev = np.array(jax.devices()[:world])
-    ctx = make_context(Mesh(dev, ("data",)))
+    ctx = make_context(make_mesh((world,), ("data",)))
     rng = np.random.default_rng(0)
     # paper Fig. 4: two relations, ~10% key uniqueness (high collision)
     nkeys = max(rows // 10, 1)

@@ -50,13 +50,13 @@ def main():
     rows = int(sys.argv[2])
     chunk = int(sys.argv[3])
     source = sys.argv[4] if len(sys.argv) > 4 else "ram"
+    from repro.launch.env import enable_compile_cache
+    enable_compile_cache()
     import jax
-    from jax.sharding import Mesh
     from repro.core import morsel as M
-    from repro.core.context import make_context
+    from repro.core.context import make_context, make_mesh
 
-    dev = np.array(jax.devices()[:world])
-    ctx = make_context(Mesh(dev, ("data",)))
+    ctx = make_context(make_mesh((world,), ("data",)))
     rng = np.random.default_rng(0)
     nkeys = max(rows // 10, 1)
     left = {"k": rng.integers(0, nkeys, rows).astype(np.int32),

@@ -38,15 +38,15 @@ def main():
     gen_cap = int(sys.argv[5])
     queue_cap = int(sys.argv[6])
 
+    from repro.launch.env import enable_compile_cache
+    enable_compile_cache()
     import jax
-    from jax.sharding import Mesh
     from repro.configs import get_reduced
-    from repro.core.context import make_context
+    from repro.core.context import make_context, make_mesh
     from repro.models import model as M
     from repro.serving import FeatureStore, Request, ServingEngine
 
-    dev = np.array(jax.devices()[:world])
-    ctx = make_context(Mesh(dev, ("data",)))
+    ctx = make_context(make_mesh((world,), ("data",)))
     cfg = get_reduced("lm100m")
     params = M.init_params(jax.random.PRNGKey(0), cfg)
 

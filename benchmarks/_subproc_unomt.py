@@ -15,18 +15,19 @@ def main():
     world = int(sys.argv[1])
     n = int(sys.argv[2])
     do_train = len(sys.argv) > 3 and sys.argv[3] == "train"
+    from repro.launch.env import enable_compile_cache
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core import dist_ops as D
-    from repro.core.context import make_context
+    from repro.core.context import make_context, make_mesh
     from repro.data.unomt import (feature_label_arrays, gen_unomt_tables,
                                   unomt_dist_pipeline)
     from repro.models import unomt_net
     from repro.optim import adamw
 
-    dev = np.array(jax.devices()[:world])
-    ctx = make_context(Mesh(dev, ("data",)))
+    ctx = make_context(make_mesh((world,), ("data",)))
     raw = gen_unomt_tables(n_response=n, n_drugs=512, n_cells=256, seed=0)
     caps = {k: max((len(next(iter(v.values()))) // world) * 2, 8)
             for k, v in raw.items()}

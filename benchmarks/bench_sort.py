@@ -34,8 +34,7 @@ def numpy_sort_baseline(keys: np.ndarray, vals: np.ndarray) -> float:
 
 def run(fast: bool = False):
     from repro.core import dist_ops as D, local_ops as L
-    from repro.core.context import make_context
-    from jax.sharding import Mesh
+    from repro.core.context import make_context, make_mesh
 
     rep = Reporter("sort_local_backends")
     rows = ROWS // 4 if fast else ROWS
@@ -64,7 +63,7 @@ def run(fast: bool = False):
 
     # dist_sort leg (world 1 in-process; multi-device scaling lives in
     # tests/dist/sort_conformance.py, run under forced host devices)
-    ctx = make_context(Mesh(np.array(jax.devices()[:1]), ("data",)))
+    ctx = make_context(make_mesh((1,), ("data",)))
     data = {"k": rng.integers(-1000, 1000, rows).astype(np.int32),
             "v": rng.normal(size=rows).astype(np.float32)}
     for impl in ("xla", "radix"):

@@ -10,6 +10,8 @@ from typing import Callable
 
 import numpy as np
 
+from repro.launch.env import child_env
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 RESULTS = os.path.join(REPO, "results")
 
@@ -73,9 +75,9 @@ def load_results() -> list[dict]:
 
 def run_subprocess_bench(script: str, n_devices: int, *args,
                          timeout: int = 900) -> dict:
-    """Run a bench script with N forced host devices; parse last JSON line."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    """Run a bench script on ``n_devices`` devices (forced host devices on
+    the CPU, appended to ``XLA_FLAGS``); parse its last JSON line."""
+    env = child_env(n_devices)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "benchmarks", script),

@@ -4,8 +4,10 @@
                                             [--check-budgets]
 
 Prints ``bench,config,metric,value`` CSV rows and writes
-results/bench.json.  ``--only`` takes a comma-separated subset.  Figure
-map:
+results/bench.json.  ``--only`` takes a comma-separated subset.  Each
+bench runs in a child process of its own and this script never imports
+JAX, so whichever process runs JAX is the only one holding the chip.
+Figure map:
 
     fig4   distributed join scaling            (paper Fig. 4)
     groupby  local groupby backend sweep       (sort vs bucketed hash)
@@ -31,26 +33,27 @@ compares against committed fast-size baselines.
 from __future__ import annotations
 
 import argparse
+import importlib
+import os
+import subprocess
 import sys
 
-from . import (bench_dataparallel_de, bench_ddp_train, bench_groupby,
-               bench_join, bench_kernels, bench_outofcore, bench_roofline,
-               bench_sequential_de, bench_serve_e2e, bench_setops,
-               bench_sort)
-from .common import load_results, row_key
+from .common import REPO, load_results, row_key
 
+# bench name -> module whose ``run(fast)`` it calls (imported only in the
+# bench's own child process)
 BENCHES = {
-    "fig4": bench_join.run,
-    "groupby": bench_groupby.run,
-    "sort": bench_sort.run,
-    "setops": bench_setops.run,
-    "outofcore": bench_outofcore.run,
-    "fig12": bench_sequential_de.run,
-    "fig13": bench_dataparallel_de.run,
-    "fig16": bench_ddp_train.run,
-    "kernels": bench_kernels.run,
-    "roofline": bench_roofline.run,
-    "serve": bench_serve_e2e.run,
+    "fig4": "bench_join",
+    "groupby": "bench_groupby",
+    "sort": "bench_sort",
+    "setops": "bench_setops",
+    "outofcore": "bench_outofcore",
+    "fig12": "bench_sequential_de",
+    "fig13": "bench_dataparallel_de",
+    "fig16": "bench_ddp_train",
+    "kernels": "bench_kernels",
+    "roofline": "bench_roofline",
+    "serve": "bench_serve_e2e",
 }
 
 # metrics where lower is WORSE: gated as a lower bound (value must stay
@@ -91,6 +94,16 @@ def check_budgets(budgets: dict, factor: float) -> list[str]:
     return failures
 
 
+def run_bench_child(name: str, fast: bool) -> None:
+    """Run one bench in a fresh child process (raises if it fails)."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (os.path.join(REPO, "src"), env.get("PYTHONPATH")) if p)
+    subprocess.run([sys.executable, "-m", "benchmarks.run", "--bench", name]
+                   + (["--fast"] if fast else []),
+                   cwd=REPO, env=env, check=True)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
@@ -103,7 +116,13 @@ def main() -> None:
                          "regresses past --budget-factor x its committed "
                          "results/bench.json value")
     ap.add_argument("--budget-factor", type=float, default=1.5)
+    ap.add_argument("--bench", choices=sorted(BENCHES),
+                    help=argparse.SUPPRESS)    # child: run one bench here
     args = ap.parse_args()
+    if args.bench:
+        importlib.import_module(f"benchmarks.{BENCHES[args.bench]}") \
+            .run(fast=args.fast)
+        return
     if args.only:
         names = args.only.split(",")
         unknown = [n for n in names if n not in BENCHES]
@@ -120,7 +139,7 @@ def main() -> None:
     print("bench,config,metric,value")
     for name in names:
         print(f"# --- {name} ---", flush=True)
-        BENCHES[name](fast=args.fast)
+        run_bench_child(name, args.fast)
     if args.check_budgets:
         failures = check_budgets(budgets, args.budget_factor)
         if failures:

@@ -9,8 +9,9 @@ Pallas hash-join backend instead), and verifies the result against a
 single-partition oracle.
 """
 import argparse
-import os
 import sys
+
+from repro.launch.env import enable_compile_cache, ensure_host_devices
 
 
 def main():
@@ -21,20 +22,16 @@ def main():
                     choices=["sortmerge", "hash"])
     args = ap.parse_args()
 
-    if args.parallelism > 1 and "XLA_FLAGS" not in os.environ:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.parallelism}")
-        os.execv(sys.executable, [sys.executable] + sys.argv)
+    ensure_host_devices(args.parallelism, sys.argv)
+    enable_compile_cache()
 
-    import jax
     import numpy as np
-    from jax.sharding import Mesh
     from repro.core import dist_ops as D, local_ops as L
-    from repro.core.context import make_context
+    from repro.core.context import make_context, make_mesh
     from repro.core.table import Table
 
-    world = min(args.parallelism, len(jax.devices()))
-    ctx = make_context(Mesh(np.array(jax.devices()[:world]), ("data",)))
+    world = args.parallelism
+    ctx = make_context(make_mesh((world,), ("data",)))
     rng = np.random.default_rng(0)
     n = args.rows
     left = {"k": rng.integers(0, n // 10, n).astype(np.int32),

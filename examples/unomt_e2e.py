@@ -12,8 +12,9 @@ Stages (paper Fig. 5):
   4. data analytics       -> BSP DDP training of the drug-response net
 """
 import argparse
-import os
 import sys
+
+from repro.launch.env import enable_compile_cache, ensure_host_devices
 
 
 def main():
@@ -28,18 +29,15 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/unomt_ckpt")
     args = ap.parse_args()
 
-    if args.parallelism > 1 and "XLA_FLAGS" not in os.environ:
-        # stage 1: single-command spawn (the paper's mpirun equivalent)
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.parallelism}")
-        os.execv(sys.executable, [sys.executable] + sys.argv)
+    # stage 1: single-command spawn (the paper's mpirun equivalent)
+    ensure_host_devices(args.parallelism, sys.argv)
+    enable_compile_cache()
 
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.sharding import Mesh
     from repro.core import dist_ops as D
-    from repro.core.context import make_context
+    from repro.core.context import make_context, make_mesh
     from repro.data.unomt import (feature_label_arrays, gen_unomt_tables,
                                   unomt_dist_pipeline)
     from repro.models import unomt_net
@@ -48,8 +46,8 @@ def main():
     from repro.runtime.trainer import (FailureInjector, Trainer,
                                        run_with_restarts)
 
-    world = min(args.parallelism, len(jax.devices()))
-    ctx = make_context(Mesh(np.array(jax.devices()[:world]), ("data",)))
+    world = args.parallelism
+    ctx = make_context(make_mesh((world,), ("data",)))
     print(f"[stage 1] {world} workers, mesh axes {ctx.mesh.axis_names}")
 
     # ---- stage 2: distributed data engineering --------------------------

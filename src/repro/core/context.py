@@ -13,40 +13,42 @@ distributed operators.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Callable, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-# --------------------------------------------------------------------------
-# shard_map version shim
-# --------------------------------------------------------------------------
-# ``jax.shard_map`` (with the ``check_vma`` kwarg) only exists on newer jax
-# releases; older ones expose ``jax.experimental.shard_map.shard_map`` (with
-# the ``check_rep`` kwarg).  This is the single place the repo adapts to
-# that API drift — import :func:`shard_map` from here, never from jax
-# directly.
-
-
-def _resolve_shard_map():
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm, "check_vma"
-    from jax.experimental.shard_map import shard_map as sm
-    return sm, "check_rep"
-
-
-_SHARD_MAP, _CHECK_KW = _resolve_shard_map()
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def shard_map(f: Callable, *, mesh, in_specs, out_specs,
               check: bool = False) -> Callable:
-    """Version-portable ``shard_map`` (replication checking off by default:
-    table ops return per-shard results on purpose)."""
-    return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check})
+    """``jax.shard_map`` with replication checking off by default: table
+    ops return per-shard results on purpose."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """The one way this repo builds a device mesh.
+
+    Every axis is ``AxisType.Auto``: ``jax.make_mesh`` defaults to
+    ``Explicit`` axes, which ``with_sharding_constraint`` (the model's
+    sharding policy) refuses.  The mesh takes the first ``prod(shape)``
+    devices; asking for more devices than exist raises — a run never
+    silently shrinks to the devices there are.
+    """
+    shape, axes = tuple(int(s) for s in shape), tuple(axes)
+    need, have = math.prod(shape), len(jax.devices())
+    if need > have:
+        raise ValueError(
+            f"mesh {dict(zip(axes, shape))} needs {need} devices, "
+            f"{have} present ({jax.devices()[0].platform}); on the CPU, "
+            f"force host devices with XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={need}")
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=jax.devices()[:need])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,8 +89,7 @@ class HptmtContext:
 def make_context(mesh: Mesh | None = None,
                  row_axes: Sequence[str] | None = None) -> HptmtContext:
     if mesh is None:
-        dev = np.array(jax.devices())
-        mesh = Mesh(dev, ("data",))
+        mesh = make_mesh((len(jax.devices()),), ("data",))
     if row_axes is None:
         row_axes = ("data",) if "data" in mesh.axis_names else \
             (mesh.axis_names[0],)

@@ -280,8 +280,15 @@ def plan_dist_join_sizes(left_keys: Sequence[np.ndarray],
         planes.append(np.asarray(_bucketing.key_bits(
             jnp.asarray(np.concatenate([lc.astype(dt), rc.astype(dt)])))))
     bits = np.stack(planes, axis=1)                       # (nl+nr, K)
-    uniq, first, inv = np.unique(bits, axis=0, return_index=True,
-                                 return_inverse=True)
+    if bits.shape[1] == 1:
+        # one key plane: the 1-D unique sorts int32 directly, where the
+        # row-wise (axis=0) one sorts opaque records — minutes at 2^27
+        uniq, first, inv = np.unique(bits[:, 0], return_index=True,
+                                     return_inverse=True)
+        uniq = uniq[:, None]
+    else:
+        uniq, first, inv = np.unique(bits, axis=0, return_index=True,
+                                     return_inverse=True)
     inv = inv.reshape(-1)
     n_uniq = uniq.shape[0]
     cl = np.bincount(inv[:nl], minlength=n_uniq).astype(np.float64)

@@ -41,8 +41,8 @@ _DEFAULTS = {
 }
 # candidate grids for the measurement sweep.  radix_bits candidates keep
 # the per-pass one-hot narrow enough to materialize on any backend
-# (2**11 = 2048 columns at most); tile candidates stay VMEM-safe at the
-# widest one-hot the bucketed kernels build (tile * 513 * 4 B).
+# (2**11 = 2048 columns at most); any tile candidate is VMEM-safe because
+# the tile kernels scan 128 rows at a time (``tile_scan``).
 _CANDIDATES = {
     "radix_bits": (4, 8, 11),
     "tile": (512, 1024, 2048),

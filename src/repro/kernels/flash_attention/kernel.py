@@ -18,9 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from ..compat import TPUCompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -89,11 +87,6 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
 
     kern = functools.partial(_kernel, scale=scale, causal=causal,
                              bq=bq, bk=bk, skv=Skv, sq=Sq)
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
     return pl.pallas_call(
         kern,
         grid=grid,
@@ -112,5 +105,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
     )(q, k, v)

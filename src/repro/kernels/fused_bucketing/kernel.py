@@ -8,45 +8,48 @@ bit-planes stays in VREGs and feeds the one-hot occupancy matrix
 directly, so bucket ids are never materialized to HBM between passes.
 
 Tiling (same scheme as ``hash_partition/kernel.py``): the row axis is
-blocked into ``(n_tiles, tile)``; each grid step loads one ``(1, K,
-tile)`` slab of bit-planes plus its ``(1, tile)`` validity slab into
-VMEM, mixes the K planes into a per-row bucket id, then materializes the
-``(tile, P+1)`` one-hot (P real buckets + 1 trash column for invalid
-rows) and reduces it two ways: per-tile histogram ``(1, P+1)`` and
-within-tile ranks ``(1, tile)``.  The cross-tile exclusive scan is
-composed outside in ``ops.py``, keeping the grid embarrassingly parallel
-(``dimension_semantics=("parallel",)``).
+blocked into ``(n_tiles, ., tile)``; each grid step loads one ``(1, K,
+tile)`` slab of bit-planes plus its ``(1, 1, tile)`` validity row into
+VMEM and, one 128-row chunk at a time (``tile_scan``), mixes the K planes
+into a per-row bucket id, then reduces the ``(P+1)``-wide one-hot (P real
+buckets + 1 trash column for invalid rows) into the per-tile histogram
+``(1, P+1)`` and the within-tile ranks ``(1, tile)``.  The cross-tile
+exclusive scan is composed outside in ``ops.py``, keeping the grid
+embarrassingly parallel (``dimension_semantics=("parallel",)``).
 
-VMEM budget: tile=1024, P<=512 -> one-hot is 1024*513*4 B ~ 2 MiB, well
-under the ~16 MiB/core VMEM of TPU v5e.  ``tile`` is resolved through
-``kernels.autotune`` (``REPRO_TILE`` override).
+VMEM budget: the one-hot is ``(P+1) x 128`` per chunk whatever the tile —
+P=512 means 257 KiB, far under the 16 MiB scoped VMEM of TPU v5e.
+``tile`` is resolved through ``kernels.autotune`` (``REPRO_TILE``
+override).
 """
 import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import TPUCompilerParams
+from ..tile_scan import tile_hist_ranks
 from .ref import _GOLDEN, _mix32
 
 
 def _kernel(bits_ref, valid_ref, bid_ref, hist_ref, rank_ref, *,
             num_buckets: int, num_keys: int):
-    tile = valid_ref.shape[1]
-    h = jnp.full((tile,), jnp.uint32(_GOLDEN))
-    for k in range(num_keys):
-        u = jax.lax.bitcast_convert_type(bits_ref[0, k, :], jnp.uint32)
-        h = _mix32(h ^ (u + jnp.uint32(_GOLDEN) + (h << 6) + (h >> 2)))
-    bid = (h % jnp.uint32(num_buckets)).astype(jnp.int32)
-    bid = jnp.where(valid_ref[0, :] > 0, bid, num_buckets)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (tile, num_buckets + 1), 1)
-    onehot = (bid[:, None] == cols).astype(jnp.int32)   # (tile, P+1)
-    bid_ref[0, :] = bid
-    hist_ref[0, :] = jnp.sum(onehot, axis=0)
-    excl = jnp.cumsum(onehot, axis=0) - onehot
-    rank_ref[0, :] = jnp.sum(excl * onehot, axis=1)
+    tile = valid_ref.shape[2]
+
+    def bucket_ids(lo, hi):
+        h = jnp.full((1, hi - lo), jnp.uint32(_GOLDEN))
+        for k in range(num_keys):
+            u = jax.lax.bitcast_convert_type(bits_ref[0, k:k + 1, lo:hi],
+                                             jnp.uint32)
+            h = _mix32(h ^ (u + jnp.uint32(_GOLDEN) + (h << 6) + (h >> 2)))
+        bid = (h % jnp.uint32(num_buckets)).astype(jnp.int32)
+        bid = jnp.where(valid_ref[0, :, lo:hi] > 0, bid, num_buckets)
+        bid_ref[0, :, lo:hi] = bid
+        return bid
+
+    hist_ref[0] = tile_hist_ranks(bucket_ids, rank_ref, tile,
+                                  num_buckets + 1)
 
 
 def fused_bucket_ranks_tiles(bits_tiles: jnp.ndarray,
@@ -58,27 +61,27 @@ def fused_bucket_ranks_tiles(bits_tiles: jnp.ndarray,
     n_tiles, num_keys, tile = bits_tiles.shape
     kern = functools.partial(_kernel, num_buckets=num_buckets,
                              num_keys=num_keys)
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = TPUCompilerParams(
-            dimension_semantics=("parallel",))
-    return pl.pallas_call(
+    row_spec = pl.BlockSpec((1, 1, tile), lambda i: (i, 0, 0))
+    row_shape = jax.ShapeDtypeStruct((n_tiles, 1, tile), jnp.int32)
+    bid, hist, ranks = pl.pallas_call(
         kern,
         grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec((1, num_keys, tile), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, tile), lambda i: (i, 0)),
+            row_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, tile), lambda i: (i, 0)),
-            pl.BlockSpec((1, num_buckets + 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, tile), lambda i: (i, 0)),
+            row_spec,
+            pl.BlockSpec((1, 1, num_buckets + 1), lambda i: (i, 0, 0)),
+            row_spec,
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_tiles, tile), jnp.int32),
-            jax.ShapeDtypeStruct((n_tiles, num_buckets + 1), jnp.int32),
-            jax.ShapeDtypeStruct((n_tiles, tile), jnp.int32),
+            row_shape,
+            jax.ShapeDtypeStruct((n_tiles, 1, num_buckets + 1), jnp.int32),
+            row_shape,
         ],
         interpret=interpret,
-        **kwargs,
-    )(bits_tiles, valid_tiles)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+    )(bits_tiles, valid_tiles[:, None, :])
+    return bid[:, 0, :], hist[:, 0, :], ranks[:, 0, :]

@@ -18,9 +18,12 @@ canonical-order output assembly (representative compaction + key ranking)
 is composed outside the kernel in ``ops.py``/``local_ops`` where XLA
 handles the dynamic scatters.
 
+Occupancy, ``rep`` and ``counts`` rows travel as ``(B, 1, C)`` so every
+block's last two dimensions equal the array's own, as Mosaic requires.
+
 VMEM budget: the equality matrix dominates at ``C*C*4`` bytes — C=512
 (the full-capacity exact-sizing ceiling) means 1 MiB, far under the
-~16 MiB/core of TPU v5e.  ``C`` multiples of 128 (or at least 8) are
+16 MiB scoped VMEM of TPU v5e.  ``C`` multiples of 128 (or at least 8) are
 recommended for lane alignment.
 """
 import functools
@@ -28,26 +31,24 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from ..compat import TPUCompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(kbits_ref, occ_ref, vals_ref,
             rep_ref, counts_ref, sums_ref, mins_ref, maxs_ref,
             *, num_keys: int, num_vals: int):
-    occ = occ_ref[0, :]                                    # (C,)
+    occ = occ_ref[0, 0, :]                                 # (C,)
     eq = (occ[:, None] > 0) & (occ[None, :] > 0)           # (C, C)
     for k in range(num_keys):
         eq = eq & (kbits_ref[0, k, :][:, None]
                    == kbits_ref[0, k, :][None, :])
     m = eq.astype(jnp.int32)
-    counts_ref[0, :] = jnp.sum(m, axis=1)
+    counts_ref[0, 0, :] = jnp.sum(m, axis=1)
     cap = occ.shape[0]
     earlier = jax.lax.broadcasted_iota(jnp.int32, (cap, cap), 1) \
         < jax.lax.broadcasted_iota(jnp.int32, (cap, cap), 0)  # j < i
     rep = (occ > 0) & (jnp.sum(m * earlier.astype(jnp.int32), axis=1) == 0)
-    rep_ref[0, :] = rep.astype(jnp.int32)
+    rep_ref[0, 0, :] = rep.astype(jnp.int32)
     for v in range(num_vals):
         x = vals_ref[0, v, :][None, :]                     # (1, C)
         sums_ref[0, v, :] = jnp.sum(jnp.where(eq, x, 0.0), axis=1)
@@ -63,31 +64,23 @@ def bucket_accumulate_buckets(kbits: jnp.ndarray, occ: jnp.ndarray,
     n_buckets, num_keys, cap = kbits.shape
     num_vals = vals.shape[1]
     kern = functools.partial(_kernel, num_keys=num_keys, num_vals=num_vals)
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = TPUCompilerParams(
-            dimension_semantics=("parallel",))
     val_spec = pl.BlockSpec((1, num_vals, cap), lambda i: (i, 0, 0))
     val_shape = jax.ShapeDtypeStruct((n_buckets, num_vals, cap),
                                      jnp.float32)
-    return pl.pallas_call(
+    row_spec = pl.BlockSpec((1, 1, cap), lambda i: (i, 0, 0))
+    row_shape = jax.ShapeDtypeStruct((n_buckets, 1, cap), jnp.int32)
+    rep, counts, sums, mins, maxs = pl.pallas_call(
         kern,
         grid=(n_buckets,),
         in_specs=[
             pl.BlockSpec((1, num_keys, cap), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, cap), lambda i: (i, 0)),
+            row_spec,
             val_spec,
         ],
-        out_specs=[
-            pl.BlockSpec((1, cap), lambda i: (i, 0)),
-            pl.BlockSpec((1, cap), lambda i: (i, 0)),
-            val_spec, val_spec, val_spec,
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_buckets, cap), jnp.int32),
-            jax.ShapeDtypeStruct((n_buckets, cap), jnp.int32),
-            val_shape, val_shape, val_shape,
-        ],
+        out_specs=[row_spec, row_spec, val_spec, val_spec, val_spec],
+        out_shape=[row_shape, row_shape, val_shape, val_shape, val_shape],
         interpret=interpret,
-        **kwargs,
-    )(kbits, occ, vals)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+    )(kbits, occ[:, None, :], vals)
+    return rep[:, 0, :], counts[:, 0, :], sums, mins, maxs

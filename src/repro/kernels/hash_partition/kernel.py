@@ -1,39 +1,36 @@
 """Pallas TPU radix histogram + within-tile rank kernel.
 
-Tiling: the row axis is blocked into ``(n_tiles, tile)``; each grid step
-loads one ``(1, tile)`` slab of partition ids into VMEM, materializes the
-``(tile, P)`` one-hot occupancy matrix in VREGs and reduces it two ways:
+Tiling: the row axis is blocked into ``(n_tiles, 1, tile)`` (the unit
+middle axis keeps every block's last two dimensions equal to the array's
+own, as Mosaic requires); each grid step loads one ``(1, tile)`` row of
+partition ids into VMEM and reduces its one-hot occupancy two ways:
 
 * per-tile histogram  ``(1, P)``      (sum over rows), and
-* within-tile ranks   ``(1, tile)``   (exclusive cumsum over rows, gathered
-  at each row's own partition column).
+* within-tile ranks   ``(1, tile)``   (exclusive prefix count over rows,
+  read at each row's own partition).
 
-The cross-tile exclusive scan (cheap, ``(n_tiles, P)``) is composed outside
+The prefix count runs chunk by chunk on the MXU (``tile_scan``); the
+cross-tile exclusive scan (cheap, ``(n_tiles, P)``) is composed outside
 the kernel in ``ops.py`` — keeping the kernel embarrassingly parallel over
 tiles (``dimension_semantics=("parallel",)``).
 
-VMEM budget: tile=1024, P<=512 -> one-hot is 1024*512*4 B = 2 MiB, well
-under the ~16 MiB/core VMEM of TPU v5e.  ``tile`` and ``P`` are both
-hardware-aligned (multiples of 128 recommended).
+VMEM budget: the one-hot is ``P x 128`` per chunk whatever the tile —
+P=512 means 256 KiB, far under the 16 MiB scoped VMEM of TPU v5e.
 """
 import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import TPUCompilerParams
+from ..tile_scan import tile_hist_ranks
 
 
 def _kernel(pid_ref, hist_ref, rank_ref, *, num_partitions: int):
-    pid = pid_ref[0, :]                                    # (tile,)
-    tile = pid.shape[0]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (tile, num_partitions), 1)
-    onehot = (pid[:, None] == cols).astype(jnp.int32)      # (tile, P)
-    hist_ref[0, :] = jnp.sum(onehot, axis=0)
-    excl = jnp.cumsum(onehot, axis=0) - onehot
-    rank_ref[0, :] = jnp.sum(excl * onehot, axis=1)
+    tile = pid_ref.shape[2]
+    hist_ref[0] = tile_hist_ranks(lambda lo, hi: pid_ref[0, :, lo:hi],
+                                  rank_ref, tile, num_partitions)
 
 
 def radix_histogram_ranks_tiles(pid_tiles: jnp.ndarray, num_partitions: int,
@@ -42,22 +39,20 @@ def radix_histogram_ranks_tiles(pid_tiles: jnp.ndarray, num_partitions: int,
     ranks ``(n_tiles, tile)``)."""
     n_tiles, tile = pid_tiles.shape
     kern = functools.partial(_kernel, num_partitions=num_partitions)
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = TPUCompilerParams(
-            dimension_semantics=("parallel",))
-    return pl.pallas_call(
+    hist, ranks = pl.pallas_call(
         kern,
         grid=(n_tiles,),
-        in_specs=[pl.BlockSpec((1, tile), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((1, 1, tile), lambda i: (i, 0, 0))],
         out_specs=[
-            pl.BlockSpec((1, num_partitions), lambda i: (i, 0)),
-            pl.BlockSpec((1, tile), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, num_partitions), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, tile), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_tiles, num_partitions), jnp.int32),
-            jax.ShapeDtypeStruct((n_tiles, tile), jnp.int32),
+            jax.ShapeDtypeStruct((n_tiles, 1, num_partitions), jnp.int32),
+            jax.ShapeDtypeStruct((n_tiles, 1, tile), jnp.int32),
         ],
         interpret=interpret,
-        **kwargs,
-    )(pid_tiles)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+    )(pid_tiles[:, None, :])
+    return hist[:, 0, :], ranks[:, 0, :]

@@ -19,9 +19,12 @@ Buckets are independent (``dimension_semantics=("parallel",)``); mapping
 members back to original row order is composed outside the kernel in
 ``ops.py`` where XLA handles the dynamic scatter.
 
+Occupancy and member rows travel as ``(B, 1, n)`` so every block's last
+two dimensions equal the array's own, as Mosaic requires.
+
 VMEM budget: the match matrix dominates at ``Lc*C*4`` bytes — Lc=C=512
 (the full-capacity exact-sizing ceiling) means 1 MiB, far under the
-~16 MiB/core of TPU v5e.  ``Lc``/``C`` multiples of 128 (or at least 8)
+16 MiB scoped VMEM of TPU v5e.  ``Lc``/``C`` multiples of 128 (or at least 8)
 are recommended for lane alignment.
 """
 import functools
@@ -29,20 +32,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from ..compat import TPUCompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(pbits_ref, pocc_ref, bbits_ref, bocc_ref, member_ref,
             *, num_keys: int):
-    pocc = pocc_ref[0, :]                                  # (Lc,)
-    bocc = bocc_ref[0, :]                                  # (C,)
+    pocc = pocc_ref[0, 0, :]                               # (Lc,)
+    bocc = bocc_ref[0, 0, :]                               # (C,)
     match = (pocc[:, None] > 0) & (bocc[None, :] > 0)      # (Lc, C)
     for k in range(num_keys):
         match = match & (pbits_ref[0, k, :][:, None]
                          == bbits_ref[0, k, :][None, :])
-    member_ref[0, :] = (jnp.sum(match.astype(jnp.int32), axis=1)
+    member_ref[0, 0, :] = (jnp.sum(match.astype(jnp.int32), axis=1)
                         > 0).astype(jnp.int32)
 
 
@@ -54,21 +55,20 @@ def bucket_member_buckets(pbits: jnp.ndarray, pocc: jnp.ndarray,
     n_buckets, num_keys, probe_cap = pbits.shape
     chain_cap = bbits.shape[2]
     kern = functools.partial(_kernel, num_keys=num_keys)
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = TPUCompilerParams(
-            dimension_semantics=("parallel",))
-    return pl.pallas_call(
+    member = pl.pallas_call(
         kern,
         grid=(n_buckets,),
         in_specs=[
             pl.BlockSpec((1, num_keys, probe_cap), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, probe_cap), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, probe_cap), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, num_keys, chain_cap), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, chain_cap), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, chain_cap), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, probe_cap), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_buckets, probe_cap), jnp.int32),
+        out_specs=pl.BlockSpec((1, 1, probe_cap), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_buckets, 1, probe_cap),
+                                       jnp.int32),
         interpret=interpret,
-        **kwargs,
-    )(pbits, pocc, bbits, bocc)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+    )(pbits, pocc[:, None, :], bbits, bocc[:, None, :])
+    return member[:, 0, :]

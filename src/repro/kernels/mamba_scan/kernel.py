@@ -17,9 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from ..compat import TPUCompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, d_ref, A_ref, B_ref, C_ref, D_ref, y_ref, h_ref, *,
@@ -58,10 +56,6 @@ def selective_scan_pallas(x, delta, A, Bm, Cm, D, *, be: int = 256,
     D2 = D.reshape(1, E)
 
     kern = functools.partial(_kernel, chunk=chunk)
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
     y = pl.pallas_call(
         kern,
         grid=grid,
@@ -77,6 +71,7 @@ def selective_scan_pallas(x, delta, A, Bm, Cm, D, *, be: int = 256,
         out_shape=jax.ShapeDtypeStruct((Bsz, S, E), x.dtype),
         scratch_shapes=[pltpu.VMEM((be, N), jnp.float32)],
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x, delta, A, Bm, Cm, D2)
     return y

@@ -1,46 +1,45 @@
 """Pallas TPU radix digit histogram + within-tile rank kernel.
 
 The hot loop of one LSD radix pass.  Tiling mirrors the ``hash_partition``
-kernel: the row axis is blocked into ``(n_tiles, tile)``; each grid step
-loads one ``(1, tile)`` slab of int32 *sort words* into VMEM, extracts the
-``radix_bits``-wide digit at ``shift`` in VREGs (arithmetic shift + mask —
-exact at every offset because the mask discards sign-extension bits),
-materializes the ``(tile, D)`` one-hot digit occupancy and reduces it two
-ways:
+kernel: the row axis is blocked into ``(n_tiles, 1, tile)``; each grid
+step loads one ``(1, tile)`` row of int32 *sort words* into VMEM, extracts
+the ``radix_bits``-wide digit at ``shift`` in VREGs (arithmetic shift +
+mask — exact at every offset because the mask discards sign-extension
+bits), and reduces the one-hot digit occupancy two ways:
 
 * per-tile digit histogram  ``(1, D)``    (sum over rows), and
-* within-tile digit ranks   ``(1, tile)`` (exclusive cumsum over rows,
-  gathered at each row's own digit column).
+* within-tile digit ranks   ``(1, tile)`` (exclusive prefix count over
+  rows, read at each row's own digit; chunked on the MXU by
+  ``tile_scan``).
 
 Fusing digit extraction into the kernel means a pass streams each word
 through VMEM exactly once; the cross-tile exclusive scan (cheap,
 ``(n_tiles, D)``) is composed outside in ``ops.py``, keeping the kernel
 embarrassingly parallel over tiles.
 
-VMEM budget: tile=1024, D=256 (the 8-bit default) -> one-hot is
-1024*256*4 B = 1 MiB, well under ~16 MiB/core; the 1-bit compaction fast
-path (D=2) is a sliver.
+VMEM budget: the one-hot is ``D x 128`` per chunk whatever the tile —
+D=256 (the 8-bit default) is 128 KiB, D=2048 (11 bits) 1 MiB; the 1-bit
+compaction fast path (D=2) is a sliver.
 """
 import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import TPUCompilerParams
+from ..tile_scan import tile_hist_ranks
 
 
 def _kernel(words_ref, hist_ref, rank_ref, *, shift: int, radix_bits: int):
-    words = words_ref[0, :]                                # (tile,)
-    tile = words.shape[0]
+    tile = words_ref.shape[2]
     num_digits = 1 << radix_bits
-    d = (words >> shift) & jnp.int32(num_digits - 1)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (tile, num_digits), 1)
-    onehot = (d[:, None] == cols).astype(jnp.int32)        # (tile, D)
-    hist_ref[0, :] = jnp.sum(onehot, axis=0)
-    excl = jnp.cumsum(onehot, axis=0) - onehot
-    rank_ref[0, :] = jnp.sum(excl * onehot, axis=1)
+
+    def digits(lo, hi):
+        return (words_ref[0, :, lo:hi] >> shift) \
+            & jnp.int32(num_digits - 1)
+
+    hist_ref[0] = tile_hist_ranks(digits, rank_ref, tile, num_digits)
 
 
 def digit_histogram_ranks_tiles(word_tiles: jnp.ndarray, shift: int,
@@ -51,22 +50,20 @@ def digit_histogram_ranks_tiles(word_tiles: jnp.ndarray, shift: int,
     n_tiles, tile = word_tiles.shape
     num_digits = 1 << radix_bits
     kern = functools.partial(_kernel, shift=shift, radix_bits=radix_bits)
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = TPUCompilerParams(
-            dimension_semantics=("parallel",))
-    return pl.pallas_call(
+    hist, ranks = pl.pallas_call(
         kern,
         grid=(n_tiles,),
-        in_specs=[pl.BlockSpec((1, tile), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((1, 1, tile), lambda i: (i, 0, 0))],
         out_specs=[
-            pl.BlockSpec((1, num_digits), lambda i: (i, 0)),
-            pl.BlockSpec((1, tile), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, num_digits), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, tile), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_tiles, num_digits), jnp.int32),
-            jax.ShapeDtypeStruct((n_tiles, tile), jnp.int32),
+            jax.ShapeDtypeStruct((n_tiles, 1, num_digits), jnp.int32),
+            jax.ShapeDtypeStruct((n_tiles, 1, tile), jnp.int32),
         ],
         interpret=interpret,
-        **kwargs,
-    )(word_tiles)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+    )(word_tiles[:, None, :])
+    return hist[:, 0, :], ranks[:, 0, :]

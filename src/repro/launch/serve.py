@@ -15,27 +15,39 @@ metrics snapshot (counters / gauges / latency summaries) and asserts the
 accounting identity: submitted == completed + rejected + feature_misses.
 """
 import argparse
-import os
+import math
 import sys
 import time
 
+from .env import enable_compile_cache, ensure_host_devices
 
-_COUNT_FLAG = "--xla_force_host_platform_device_count"
+
+N_DRUGS, N_CELLS = 256, 128
 
 
-def ensure_host_devices(n: int, module: str) -> None:
-    """Re-exec with ``XLA_FLAGS`` requesting ``n`` host devices — *merging*
-    with any flags already set (replacing a stale device-count flag,
-    keeping everything else) instead of skipping when ``XLA_FLAGS``
-    exists.  No-op (so the re-exec terminates) once the flag is right."""
-    want = f"{_COUNT_FLAG}={n}"
-    flags = os.environ.get("XLA_FLAGS", "").split()
-    if want in flags:
-        return
-    flags = [f for f in flags if not f.startswith(_COUNT_FLAG)]
-    os.environ["XLA_FLAGS"] = " ".join(flags + [want])
-    os.execv(sys.executable,
-             [sys.executable, "-m", module] + sys.argv[1:])
+def unomt_feature_stores(ctx, *, slots: int, seed: int = 0) -> dict:
+    """The drug and cell feature stores a request's keys resolve against:
+    UNOMT descriptor+fingerprint rows per drug, RNA rows per cell line."""
+    import numpy as np
+
+    from ..data.unomt import gen_unomt_tables
+    from ..serving import FeatureStore
+
+    raw = gen_unomt_tables(n_drugs=N_DRUGS, n_cells=N_CELLS, seed=seed)
+    drug = dict(raw["descriptors"])
+    drug.update({k: v for k, v in raw["fingerprints"].items()
+                 if k != "drug_id"})
+    # rna carries duplicate records (paper: drop-duplicates) — keep the
+    # first row per key so store keys are unique
+    _, first = np.unique(raw["rna"]["cell_id"], return_index=True)
+    rna = {k: v[first] for k, v in raw["rna"].items()}
+    cap = max(slots, 8)
+    return {
+        "drug_id": FeatureStore(ctx, "drug_id", drug, probe_capacity=cap,
+                                chunk_rows=64),
+        "cell_id": FeatureStore(ctx, "cell_id", rna, probe_capacity=cap,
+                                chunk_rows=64),
+    }
 
 
 def main():
@@ -55,52 +67,35 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    shape = {}
     if args.mesh:
-        n = 1
-        for kv in args.mesh.split(","):
-            n *= int(kv.split("=")[1])
-        ensure_host_devices(n, "repro.launch.serve")
+        shape = {kv.split("=")[0]: int(kv.split("=")[1])
+                 for kv in args.mesh.split(",")}
+        ensure_host_devices(math.prod(shape.values()),
+                            ["-m", "repro.launch.serve", *sys.argv[1:]])
+    enable_compile_cache()
 
     import jax
     import numpy as np
 
     from ..configs import get_config, get_reduced
-    from ..core.context import make_context
-    from ..data.unomt import gen_unomt_tables
+    from ..core.context import make_context, make_mesh
     from ..models import model as M
     from ..models.sharding import make_policy
-    from ..serving import FeatureStore, Request, ServingEngine
+    from ..serving import Request, ServingEngine
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     policy = None
-    if args.mesh:
-        shape = {kv.split("=")[0]: int(kv.split("=")[1])
-                 for kv in args.mesh.split(",")}
-        mesh = jax.make_mesh(tuple(shape.values()), tuple(shape.keys()))
+    if shape:
+        mesh = make_mesh(tuple(shape.values()), tuple(shape.keys()))
         policy = make_policy(mesh, "fsdp_tp")
 
     params = M.init_params(jax.random.PRNGKey(0), cfg)
 
     stores = {}
-    n_drugs, n_cells = 256, 128
     if not args.no_features:
-        ctx = make_context()
-        raw = gen_unomt_tables(n_drugs=n_drugs, n_cells=n_cells,
-                               seed=args.seed)
-        drug = dict(raw["descriptors"])
-        drug.update({k: v for k, v in raw["fingerprints"].items()
-                     if k != "drug_id"})
-        # rna carries duplicate records (paper: drop-duplicates) — keep
-        # the first row per key so store keys are unique
-        _, first = np.unique(raw["rna"]["cell_id"], return_index=True)
-        rna = {k: v[first] for k, v in raw["rna"].items()}
-        cap = max(args.slots, 8)
-        stores = {
-            "drug_id": FeatureStore(ctx, "drug_id", drug,
-                                    probe_capacity=cap, chunk_rows=64),
-            "cell_id": FeatureStore(ctx, "cell_id", rna,
-                                    probe_capacity=cap, chunk_rows=64),
-        }
+        stores = unomt_feature_stores(make_context(), slots=args.slots,
+                                      seed=args.seed)
 
     engine = ServingEngine(cfg, params, policy=policy, slots=args.slots,
                            prompt_capacity=args.prompt_len,
@@ -118,8 +113,8 @@ def main():
                                 rng.integers(1, args.prompt_len + 1)
                                 ).astype(np.int32),
             gen_len=int(rng.integers(1, args.gen + 1)),
-            drug_id=int(rng.integers(0, n_drugs)),
-            cell_id=int(rng.integers(0, n_cells)))
+            drug_id=int(rng.integers(0, N_DRUGS)),
+            cell_id=int(rng.integers(0, N_CELLS)))
         if not engine.submit(req):
             rejected_ids.append(i)
         if (i + 1) % max(args.slots * 4, 8) == 0:

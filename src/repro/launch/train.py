@@ -3,7 +3,7 @@ equivalent) for LM training with the full fault-tolerance stack.
 
     PYTHONPATH=src python -m repro.launch.train --arch lm100m \
         [--steps 300] [--batch 8] [--seq 512] [--reduced]
-        [--mesh data=2,model=2]        # forced host devices (re-execs)
+        [--mesh data=2,model=2]        # on the CPU: forced host devices
         [--ckpt-dir /tmp/lm_ckpt] [--ckpt-every 50]
         [--fail-at 120]                # failure-injection drill
         [--resume]                     # restore latest checkpoint
@@ -14,8 +14,10 @@ logic and the step function are identical; the SPMD program does not
 change (loosely-synchronous model: no central scheduler).
 """
 import argparse
-import os
+import math
 import sys
+
+from .env import enable_compile_cache, ensure_host_devices
 
 
 def _parse_mesh(s: str) -> dict:
@@ -35,7 +37,8 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=512)
     ap.add_argument("--mesh", default=None,
-                    help="e.g. data=2,model=2 (forces host devices)")
+                    help="e.g. data=2,model=2 (on the CPU, forces host "
+                         "devices; on chips, needs that many)")
     ap.add_argument("--ckpt-dir", default="/tmp/repro_lm_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--fail-at", type=int, default=None)
@@ -48,14 +51,10 @@ def main():
     args = ap.parse_args()
 
     mesh_shape = _parse_mesh(args.mesh) if args.mesh else None
-    if mesh_shape and "XLA_FLAGS" not in os.environ:
-        n = 1
-        for v in mesh_shape.values():
-            n *= v
-        os.environ["XLA_FLAGS"] = \
-            f"--xla_force_host_platform_device_count={n}"
-        os.execv(sys.executable,
-                  [sys.executable, "-m", "repro.launch.train"] + sys.argv[1:])
+    if mesh_shape:
+        ensure_host_devices(math.prod(mesh_shape.values()),
+                            ["-m", "repro.launch.train", *sys.argv[1:]])
+    enable_compile_cache()
 
     import jax
     if args.coordinator:
@@ -65,6 +64,7 @@ def main():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ..configs import get_config, get_reduced
+    from ..core.context import make_mesh
     from ..data.synthetic import lm_batch_at
     from ..models import model as M
     from ..models.sharding import make_policy
@@ -74,8 +74,8 @@ def main():
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     if mesh_shape:
-        mesh = jax.make_mesh(tuple(mesh_shape.values()),
-                             tuple(mesh_shape.keys()))
+        mesh = make_mesh(tuple(mesh_shape.values()),
+                         tuple(mesh_shape.keys()))
         policy = make_policy(mesh, cfg.train.sharding)
     else:
         mesh, policy = None, None
