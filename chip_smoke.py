@@ -69,17 +69,11 @@ def log(phase: str, msg: str) -> None:
     print(f"[{phase}] {msg}", flush=True)
 
 
-def compile_clock():
-    """A function giving the seconds XLA has spent compiling in this
-    process since the call (persistent-cache hits compile nothing)."""
-    import jax
-    total = [0.0]
-
-    def on_event(event: str, secs: float, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            total[0] += secs
-    jax.monitoring.register_event_duration_secs_listener(on_event)
-    return lambda: total[0]
+def compile_seconds() -> float:
+    """Seconds XLA has spent compiling in this process, from the program's
+    own counter (persistent-cache hits compile nothing)."""
+    from repro import trace
+    return trace.counters()["compile_s"]
 
 
 def device_bytes() -> str:
@@ -423,17 +417,16 @@ def main() -> None:
             os.environ.setdefault("REPRO_KERNEL_IMPL", "pallas_interpret")
             ensure_host_devices(args.chips, sys.argv)
     enable_compile_cache()
-    compiled = compile_clock()
 
     device = phase_device(args.rehearse, args.chips)
     impl = "pallas" if device["platform"] == "tpu" else "pallas_interpret"
     if args.chips == 4:
-        phase_tables(sizes, 4, args.seed, compiled)
+        phase_tables(sizes, 4, args.seed, compile_seconds)
     else:
         phase_kernels(sizes, impl, args.seed)
-        phase_tables(sizes, 1, args.seed, compiled)
-        phase_serving(sizes, args.seed, compiled)
-        phase_training(sizes, args.seed, compiled)
+        phase_tables(sizes, 1, args.seed, compile_seconds)
+        phase_serving(sizes, args.seed, compile_seconds)
+        phase_training(sizes, args.seed, compile_seconds)
     print(json.dumps({"ok": True, "device": device}), flush=True)
 
 

@@ -69,6 +69,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import local_ops as L
+from .. import trace
 from .context import HptmtContext, shard_map
 from .kernel_backend import radix_impl
 from .kernel_backend import sort_impl as _default_sort_impl
@@ -100,44 +101,51 @@ def distribute_table(ctx: HptmtContext, data: Mapping[str, np.ndarray],
     world = ctx.world_size
     arrays = {k: np.asarray(v) for k, v in data.items()}
     n = len(next(iter(arrays.values())))
-    per = math.ceil(n / world) if n else 1
-    if capacity_per_shard is None:
-        cap = per
-    else:
-        if capacity_per_shard <= 0:
-            raise ValueError(
-                f"capacity_per_shard must be positive, got "
-                f"{capacity_per_shard} (pass None for rows-per-shard)")
-        cap = capacity_per_shard
-    if cap < per:
-        raise ValueError(f"capacity_per_shard {cap} < rows/shard {per}")
-    cols, nvalid = {}, np.zeros((world,), np.int32)
-    for s in range(world):
-        lo, hi = min(s * per, n), min((s + 1) * per, n)
-        nvalid[s] = hi - lo
-    for k, v in arrays.items():
-        v = _narrow_column(k, v)
-        buf = np.zeros((world, cap), v.dtype)
+    with trace.span("table/distribute", rows=n, world=world):
+        per = math.ceil(n / world) if n else 1
+        if capacity_per_shard is None:
+            cap = per
+        else:
+            if capacity_per_shard <= 0:
+                raise ValueError(
+                    f"capacity_per_shard must be positive, got "
+                    f"{capacity_per_shard} (pass None for rows-per-shard)")
+            cap = capacity_per_shard
+        if cap < per:
+            raise ValueError(f"capacity_per_shard {cap} < rows/shard {per}")
+        cols, nvalid = {}, np.zeros((world,), np.int32)
         for s in range(world):
             lo, hi = min(s * per, n), min((s + 1) * per, n)
-            buf[s, : hi - lo] = v[lo:hi]
-        cols[k] = jax.device_put(
-            buf.reshape(world * cap),
-            NamedSharding(ctx.mesh, ctx.rows_spec))
-    nvalid = jax.device_put(jnp.asarray(nvalid),
-                            NamedSharding(ctx.mesh, ctx.rows_spec))
-    return Table(columns=cols, nvalid=nvalid)
+            nvalid[s] = hi - lo
+        for k, v in arrays.items():
+            v = _narrow_column(k, v)
+            buf = np.zeros((world, cap), v.dtype)
+            for s in range(world):
+                lo, hi = min(s * per, n), min((s + 1) * per, n)
+                buf[s, : hi - lo] = v[lo:hi]
+            cols[k] = jax.device_put(
+                buf.reshape(world * cap),
+                NamedSharding(ctx.mesh, ctx.rows_spec))
+        nvalid = jax.device_put(jnp.asarray(nvalid),
+                                NamedSharding(ctx.mesh, ctx.rows_spec))
+        return Table(columns=cols, nvalid=nvalid)
 
 
 def collect_table(ctx: HptmtContext, table: Table) -> dict[str, np.ndarray]:
     """Host-side: gather a global row-sharded Table back to numpy (valid
-    rows only, shard order preserved)."""
+    rows only, shard order preserved).  The ``table/device_wait`` span
+    waits for the program that makes ``table``, so what is left of
+    ``table/collect`` is the copy and the host-side concatenation."""
     world = ctx.world_size
-    nvalid = np.asarray(table.nvalid).reshape(world)
-    out = {}
-    for k, v in table.columns.items():
-        v = np.asarray(v).reshape(world, -1)
-        out[k] = np.concatenate([v[s, : nvalid[s]] for s in range(world)])
+    with trace.span("table/collect"):
+        with trace.span("table/device_wait"):
+            jax.block_until_ready(table)
+        nvalid = np.asarray(table.nvalid).reshape(world)
+        out = {}
+        for k, v in table.columns.items():
+            v = np.asarray(v).reshape(world, -1)
+            out[k] = np.concatenate([v[s, : nvalid[s]]
+                                     for s in range(world)])
     return out
 
 
@@ -157,6 +165,7 @@ def _to_global(table: Table) -> Table:
 # --------------------------------------------------------------------------
 
 
+@jax.named_scope("shuffle")
 def shuffle_by_pid(ctx: HptmtContext, table: Table, pid: jnp.ndarray,
                    slots_per_dest: int, out_capacity: int):
     """Route each valid row to shard ``pid[row]`` via one ``all_to_all``.
@@ -227,6 +236,7 @@ def _pad8(load: float, headroom: float) -> int:
     return max(8, -(-int(math.ceil(load * headroom)) // 8) * 8)
 
 
+@trace.span("table/plan_join_sizes")
 def plan_dist_join_sizes(left_keys: Sequence[np.ndarray],
                          right_keys: Sequence[np.ndarray], *, world: int,
                          how: str = "inner", headroom: float = 1.25,
@@ -706,6 +716,10 @@ class DistributedPipeline:
             return jax.tree_util.tree_map(
                 lift, out, is_leaf=lambda x: isinstance(x, Table))
 
+        # the program is named after `fn` (jit_<fn>), so each pipeline is
+        # told apart in a trace's modules
+        wrapped.__name__ = wrapped.__qualname__ = getattr(
+            self.fn, "__name__", "pipeline")
         # `spec` is a valid pytree *prefix* for the whole in/out trees
         f = shard_map(wrapped, mesh=ctx.mesh, in_specs=spec,
                       out_specs=spec)

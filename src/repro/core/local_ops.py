@@ -100,6 +100,7 @@ def _sentinel_max(col: jax.Array) -> jax.Array:
     return jnp.asarray(jnp.iinfo(col.dtype).max, col.dtype)
 
 
+@jax.named_scope("select")
 def compact(table: Table, keep: jax.Array,
             kernel_impl: str | None = None) -> Table:
     """Move rows where ``keep`` holds to the front (stable); drop the rest.
@@ -155,6 +156,7 @@ def concat(a: Table, b: Table) -> Table:
     return Table(columns=cols, nvalid=a.nvalid + b.nvalid)
 
 
+@jax.named_scope("append")
 def append_rows(acc: Table, t: Table):
     """Append ``t``'s valid rows after ``acc``'s, *keeping acc's static
     capacity* (unlike :func:`concat`, which grows it).
@@ -412,6 +414,7 @@ def groupby_aggregate(table: Table, by: Sequence[str],
     return out
 
 
+@jax.named_scope("groupby")
 def _sort_groupby(table: Table, by: list,
                   aggs: Mapping[str, list]) -> Table:
     """Sort backend: lexicographic sort, group-boundary detection, segment
@@ -773,33 +776,37 @@ def _sortmerge_join(left: Table, right: Table, left_on, right_on, how,
     qkeys = tuple(left.columns[k].astype(dt)
                   for k, dt in zip(left_on, dts))
     rkeys = tuple(rk.astype(dt) for rk, dt in zip(rkeys, dts))
-    lo = lex_searchsorted(rkeys, qkeys, side="left")
-    hi = lex_searchsorted(rkeys, qkeys, side="right")
+    with jax.named_scope("join/match"):
+        lo = lex_searchsorted(rkeys, qkeys, side="left")
+        hi = lex_searchsorted(rkeys, qkeys, side="right")
     lo = jnp.minimum(lo, right.nvalid)
     hi = jnp.minimum(hi, right.nvalid)
     lvalid = left.valid_mask
     match_counts = jnp.where(lvalid, hi - lo, 0)
     cum, offs, total = _emit_layout(match_counts, lvalid, how)
 
-    j = jnp.arange(out_cap, dtype=jnp.int32)
-    lrow = jnp.searchsorted(cum, j, side="right").astype(jnp.int32)
-    lrow = jnp.clip(lrow, 0, left.capacity - 1)
-    within = j - offs[lrow]
-    matched = within < match_counts[lrow]
-    rrow = jnp.clip(lo[lrow] + within, 0, max(right.capacity - 1, 0))
+    # output slot j -> (left row, match offset): a searchsorted over the
+    # emit layout, then the gathers of every column
+    with jax.named_scope("join/expand"):
+        j = jnp.arange(out_cap, dtype=jnp.int32)
+        lrow = jnp.searchsorted(cum, j, side="right").astype(jnp.int32)
+        lrow = jnp.clip(lrow, 0, left.capacity - 1)
+        within = j - offs[lrow]
+        matched = within < match_counts[lrow]
+        rrow = jnp.clip(lo[lrow] + within, 0, max(right.capacity - 1, 0))
 
-    cols: dict[str, jax.Array] = {}
-    for n in left.names:
-        cols[n] = left.columns[n][lrow]
-    drop_keys = set(right_on) if left_on == right_on else set()
-    for n in rs.names:
-        if n in drop_keys:
-            continue
-        name = n + suffix if n in cols else n
-        v = rs.columns[n][rrow]
-        if how == "left":
-            v = jnp.where(matched, v, null_like(v))
-        cols[name] = v
+        cols: dict[str, jax.Array] = {}
+        for n in left.names:
+            cols[n] = left.columns[n][lrow]
+        drop_keys = set(right_on) if left_on == right_on else set()
+        for n in rs.names:
+            if n in drop_keys:
+                continue
+            name = n + suffix if n in cols else n
+            v = rs.columns[n][rrow]
+            if how == "left":
+                v = jnp.where(matched, v, null_like(v))
+            cols[name] = v
     out = Table(columns=cols, nvalid=jnp.minimum(total, out_cap))
     if return_overflow:
         return out, jnp.maximum(total - out_cap, 0)

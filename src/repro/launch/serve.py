@@ -11,8 +11,10 @@ queue, and runs the engine until drained — continuous batching refills
 freed decode slots while the rest of the batch keeps generating, and
 every request's keys resolve against UNOMT feature tables through the
 distributed join path before its prompt enters a slot.  Prints the full
-metrics snapshot (counters / gauges / latency summaries) and asserts the
-accounting identity: submitted == completed + rejected + feature_misses.
+metrics snapshot (counters / gauges / latency summaries), one line per
+program span (``repro.trace``: count, p50, max, self p50) and the
+process's compile and GC counters, and asserts the accounting identity:
+submitted == completed + rejected + feature_misses.
 """
 import argparse
 import math
@@ -78,6 +80,7 @@ def main():
     import jax
     import numpy as np
 
+    from .. import trace
     from ..configs import get_config, get_reduced
     from ..core.context import make_context, make_mesh
     from ..models import model as M
@@ -135,6 +138,13 @@ def main():
         if s["count"]:
             print(f"  series  {k:>18} = p50 {s['p50'] * 1e3:.1f}ms "
                   f"p99 {s['p99'] * 1e3:.1f}ms n={s['count']}")
+    for k, s in trace.summary().items():
+        print(f"  span    {k:>20} = n={s['count']} p50 "
+              f"{s['p50_s'] * 1e3:.3f}ms max {s['max_s'] * 1e3:.3f}ms "
+              f"self p50 {s['self_p50_s'] * 1e3:.3f}ms")
+    c = trace.counters()
+    print(f"  process compiles {c['compiles']} ({c['compile_s']:.2f}s), "
+          f"gc collections by generation {c['gc']}")
     assert m.count("submitted") == m.count("completed") + \
         m.count("rejected") + m.count("feature_misses"), \
         "accounting identity violated"

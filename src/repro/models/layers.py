@@ -164,22 +164,23 @@ def attn_decode(p, cfg, x, cache, cache_len, *, cross=False, policy=None):
         q = rope(q, pos, cfg.rope_theta)
         k_new = rope(k_new, pos, cfg.rope_theta)
         # one-hot scatter write (shard-friendly on a sharded S axis)
-        S = cache["k"].shape[2]
-        if cl.ndim == 0:
-            onehot = (jnp.arange(S) == cl).astype(cache["k"].dtype)
-            oh = onehot[None, None, :, None]
-        else:                       # per-slot write position: (B,1,S,1)
-            onehot = (jnp.arange(S)[None, :] == cl[:, None]) \
-                .astype(cache["k"].dtype)
-            oh = onehot[:, None, :, None]
-        cache = {
-            "k": cache["k"] * (1 - oh) + k_new.astype(cache["k"].dtype) * oh,
-            "v": cache["v"] * (1 - oh) + v_new.astype(cache["v"].dtype) * oh,
-        }
+        with jax.named_scope("decode/cache_write"):
+            S = cache["k"].shape[2]
+            if cl.ndim == 0:
+                onehot = (jnp.arange(S) == cl).astype(cache["k"].dtype)
+                oh = onehot[None, None, :, None]
+            else:                   # per-slot write position: (B,1,S,1)
+                onehot = (jnp.arange(S)[None, :] == cl[:, None]) \
+                    .astype(cache["k"].dtype)
+                oh = onehot[:, None, :, None]
+            dt = cache["k"].dtype
+            cache = {"k": cache["k"] * (1 - oh) + k_new.astype(dt) * oh,
+                     "v": cache["v"] * (1 - oh) + v_new.astype(dt) * oh}
         live_len = cache_len
     else:
         live_len = cache["k"].shape[2] - 1          # full encoder memory
-    o = A.decode_attention(q, cache["k"], cache["v"], live_len)
+    with jax.named_scope("decode/attention"):
+        o = A.decode_attention(q, cache["k"], cache["v"], live_len)
     B = x.shape[0]
     y = o.transpose(0, 2, 1, 3).reshape(B, 1, cfg.n_heads * cfg.d_head)
     return dense(p["wo"], y), cache

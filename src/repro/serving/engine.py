@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import trace
 from ..core import dist_ops as D
 from ..core import local_ops as L
 from ..core import morsel as Mo
@@ -328,20 +329,22 @@ class ServingEngine:
         ``feature_miss``es.  Returns the requests that resolved fully."""
         if not self.feature_stores:
             return reqs
-        ok = np.ones(len(reqs), bool)
-        fetched: dict[int, dict] = {i: {} for i in range(len(reqs))}
-        for attr, store in self.feature_stores.items():
-            keys = np.asarray([getattr(r, attr) for r in reqs])
-            feats, found = store.lookup(keys)
-            ok &= found
-            self.metrics.inc("feature_rows", int(found.sum()))
-            if store.dropped:
-                self.metrics.counters["feature_dropped"] = sum(
-                    s.dropped for s in self.feature_stores.values())
-            for i in range(len(reqs)):
-                if found[i]:
-                    for name, col in feats.items():
-                        fetched[i][name] = float(col[i])
+        with trace.span("serve/feature_fetch",
+                        req_ids=[r.req_id for r in reqs]):
+            ok = np.ones(len(reqs), bool)
+            fetched: dict[int, dict] = {i: {} for i in range(len(reqs))}
+            for attr, store in self.feature_stores.items():
+                keys = np.asarray([getattr(r, attr) for r in reqs])
+                feats, found = store.lookup(keys)
+                ok &= found
+                self.metrics.inc("feature_rows", int(found.sum()))
+                if store.dropped:
+                    self.metrics.counters["feature_dropped"] = sum(
+                        s.dropped for s in self.feature_stores.values())
+                for i in range(len(reqs)):
+                    if found[i]:
+                        for name, col in feats.items():
+                            fetched[i][name] = float(col[i])
         good = []
         for i, r in enumerate(reqs):
             if ok[i]:
@@ -360,11 +363,23 @@ class ServingEngine:
         n = min(len(free), len(self.queue))
         if n == 0:
             return
-        reqs = [self.queue.pop() for _ in range(n)]
-        reqs = self._fetch_features(reqs)
-        for r in reqs:
-            slot = self.batch.free()[0]
-            prompt_len = len(r.prompt)
+        with trace.span("serve/refill", n=n):
+            now = self.clock()
+            reqs = [self.queue.pop() for _ in range(n)]
+            for r in reqs:                  # admitted: taken off the queue
+                r.t_admit = now
+                self.metrics.observe("queue_wait", now - r.t_submit)
+            for r in self._fetch_features(reqs):
+                self._prefill_into(r, self.batch.free()[0])
+        self.metrics.gauge("slot_occupancy", self.batch.occupancy)
+
+    def _prefill_into(self, r: Request, slot: int) -> None:
+        """Prefill ``r``'s padded prompt, write its cache into ``slot`` and
+        take its first token (a host sync)."""
+        prompt_len = len(r.prompt)
+        with trace.span("serve/prefill", req_id=r.req_id, slot=slot,
+                        prompt_len=prompt_len,
+                        positions=self.prompt_capacity):
             padded = np.zeros((1, self.prompt_capacity), np.int32)
             padded[0, :prompt_len] = r.prompt
             first, one = self._prefill(
@@ -372,22 +387,18 @@ class ServingEngine:
                 jnp.int32(prompt_len))
             self.caches = self._insert(self.caches, one, jnp.int32(slot))
             first_tok = int(first[0])
-            now = self.clock()
-            r.t_admit = now
-            r.t_first = now
-            r.status = "active"
-            r.out_tokens.append(first_tok)
-            self.metrics.inc("admitted")
-            self.metrics.inc("prefills")
-            self.metrics.inc("tokens_generated")
-            self.metrics.observe("queue_wait", now - r.t_submit)
-            self.metrics.observe("ttft", now - r.t_submit)
-            if r.gen_len == 1:          # prefill's token was the answer
-                self._complete(r)
-                continue
-            self.batch.occupy(slot, r, first_token=first_tok,
-                              prompt_len=prompt_len, gen_target=r.gen_len)
-        self.metrics.gauge("slot_occupancy", self.batch.occupancy)
+        r.t_first = self.clock()
+        r.status = "active"
+        r.out_tokens.append(first_tok)
+        self.metrics.inc("admitted")
+        self.metrics.inc("prefills")
+        self.metrics.inc("tokens_generated")
+        self.metrics.observe("ttft", r.t_first - r.t_submit)
+        if r.gen_len == 1:              # prefill's token was the answer
+            self._complete(r)
+            return
+        self.batch.occupy(slot, r, first_token=first_tok,
+                          prompt_len=prompt_len, gen_target=r.gen_len)
 
     def _complete(self, r: Request) -> None:
         r.status = "done"
@@ -400,23 +411,29 @@ class ServingEngine:
     def step(self) -> list[Request]:
         """Refill freed slots from the queue, run one decode step over the
         fixed-shape batch, and return the requests that finished."""
-        self._refill()
-        active = self.batch.active()
-        if active:
+        with trace.span("serve/step"):
+            self._refill()
+            active = self.batch.active()
+            if active:
+                self._decode_step(len(active))
+            done, self._finished = self._finished, []
+        return done
+
+    def _decode_step(self, n_active: int) -> None:
+        with trace.span("serve/decode", active=n_active):
             nxt, self.caches = self._decode(
                 self.params, self.caches,
                 jnp.asarray(self.batch.tokens),
                 jnp.asarray(self.batch.cache_lens))
-            nxt = np.asarray(nxt)
+            with trace.span("serve/device_wait"):
+                nxt = np.asarray(nxt)
             self.metrics.inc("decode_steps")
-            self.metrics.inc("tokens_generated", len(active))
+            self.metrics.inc("tokens_generated", n_active)
             finished = self.batch.advance(
                 nxt, on_token=lambda s, r, t: r.out_tokens.append(t))
             for slot in finished:
                 self._complete(self.batch.release(slot))
             self.metrics.gauge("slot_occupancy", self.batch.occupancy)
-        done, self._finished = self._finished, []
-        return done
 
     @property
     def busy(self) -> bool:

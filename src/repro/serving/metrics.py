@@ -13,7 +13,8 @@ registry so the millions-of-users story is *measurable* —
   shuffle/join slabs — must stay 0 when sized right);
 * **gauges** (last + max): ``queue_depth``, ``slot_occupancy``;
 * **series** (observations in seconds): ``latency`` (submit -> done),
-  ``ttft`` (submit -> first token), ``queue_wait`` (submit -> admit) —
+  ``ttft`` (submit -> first token), ``queue_wait`` (submit -> taken off
+  the queue, before the feature fetch and the prefill) —
   summarized as count/mean/p50/p99/max.
 
 Percentiles use the nearest-rank method over everything observed (the

@@ -13,6 +13,7 @@ feature-fetch path.
 import os
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -20,6 +21,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro import trace
 from repro.configs import get_reduced
 from repro.core.context import make_context
 from repro.models import model as M
@@ -142,17 +144,22 @@ def served():
             req_id=i, prompt=rng.integers(0, cfg.vocab, p_len
                                           ).astype(np.int32),
             gen_len=g, drug_id=(999 if i == 3 else i)))
+    t0 = time.perf_counter()
     rejected = [r for r in reqs if not eng.submit(r)]
     done = eng.run_until_drained()
     # resubmit anything rejected by the small queue (accounted above)
     for r in rejected:
         assert eng.submit(r)
     done += eng.run_until_drained()
-    return eng, store, feats, reqs, rejected, done
+    # the engine's spans of this run alone (the recorder is process-wide)
+    spans = {n: [r for r in trace.records(n) if r.start >= t0]
+             for n in ("serve/step", "serve/refill", "serve/feature_fetch",
+                       "serve/prefill", "serve/decode", "serve/device_wait")}
+    return eng, store, feats, reqs, rejected, done, spans
 
 
 def test_engine_every_admitted_request_completes(served):
-    eng, store, feats, reqs, rejected, done = served
+    eng, store, feats, reqs, rejected, done, _ = served
     m = eng.metrics
     assert m.count("submitted") == m.count("completed") + \
         m.count("rejected") + m.count("feature_misses")
@@ -186,6 +193,42 @@ def test_engine_static_batch_shape_across_refills(served):
     got = jax.tree_util.tree_map(lambda x: x.shape, eng.caches)
     want = jax.tree_util.tree_map(lambda s: s.shape, struct)
     assert got == want
+
+
+def test_engine_spans_one_prefill_per_admitted_request(served):
+    eng, _, _, reqs, _, done, spans = served
+    pre = spans["serve/prefill"]
+    admitted = [r for r in done if r.status == "done"]
+    assert len(pre) == eng.metrics.count("admitted") == len(admitted)
+    assert sorted(p.attrs["req_id"] for p in pre) == \
+        sorted(r.req_id for r in admitted)
+    for p in pre:
+        r = reqs[p.attrs["req_id"]]
+        assert p.attrs["prompt_len"] == len(r.prompt)
+        assert p.attrs["positions"] == eng.prompt_capacity
+        assert p.parent == "serve/refill"
+    fetched = sorted(i for f in spans["serve/feature_fetch"]
+                     for i in f.attrs["req_ids"])
+    assert fetched == sorted(r.req_id for r in done)   # misses included
+
+
+def test_engine_spans_one_decode_per_step(served):
+    eng, *_, spans = served
+    dec, wait = spans["serve/decode"], spans["serve/device_wait"]
+    assert len(dec) == eng.metrics.count("decode_steps") > 0
+    assert len(wait) == len(dec)
+    assert all(w.parent == "serve/decode" for w in wait)
+    assert all(d.parent == "serve/step" for d in dec)
+    host = trace.self_times("serve/decode", ["serve/device_wait"])
+    assert all(h >= 0 for h in host)
+
+
+def test_engine_queue_wait_ends_before_the_first_token(served):
+    _, _, _, reqs, _, done, _ = served
+    for r in done:
+        assert r.t_submit <= r.t_admit
+        if r.status == "done":
+            assert r.t_admit - r.t_submit <= r.t_first - r.t_submit
 
 
 def test_engine_validates_request_bounds(served):
