@@ -760,12 +760,26 @@ def _emit_layout(match_counts: jax.Array, lvalid: jax.Array, how: str):
     return cum, offs, total
 
 
+def _slot_rows(offs: jax.Array, out_cap: int) -> jax.Array:
+    """Left row of each of ``out_cap`` output slots, from the emit layout's
+    exclusive ``offs``: every row scatters its index to its first slot and
+    a running max carries it over the row's other slots — O(L + out_cap),
+    no search.  Rows sharing an offset are zero-emit rows followed by the
+    one row that emits there, so the scatter's ``max`` keeps that row.
+    Slots at or past ``min(total, out_cap)`` are padding and get some row
+    in range."""
+    rows = jnp.arange(offs.shape[0], dtype=jnp.int32)
+    first = (jnp.zeros((out_cap,), jnp.int32)
+             .at[offs].max(rows, mode="drop", indices_are_sorted=True))
+    return jax.lax.cummax(first)
+
+
 def _sortmerge_join(left: Table, right: Table, left_on, right_on, how,
                     out_cap, suffix, return_overflow):
     """Sort-merge backend: the right table is sorted by its keys; each left
     row binary-searches its match range ``[lo, hi)``; output slot ``j`` is
-    mapped back to its (left row, match offset) pair with a second
-    searchsorted — fully vectorized, no dynamic shapes."""
+    mapped back to its left row by one scatter and a running max over the
+    emit layout (``_slot_rows``) — fully vectorized, no dynamic shapes."""
     rs, rkeys = _sorted_keys_with_sentinel(right, right_on)
     # compare every key pair in the *promoted* common dtype (casting the
     # sorted keys is order-preserving: int32 -> float32 is monotonic), so
@@ -783,17 +797,19 @@ def _sortmerge_join(left: Table, right: Table, left_on, right_on, how,
     hi = jnp.minimum(hi, right.nvalid)
     lvalid = left.valid_mask
     match_counts = jnp.where(lvalid, hi - lo, 0)
-    cum, offs, total = _emit_layout(match_counts, lvalid, how)
+    _, offs, total = _emit_layout(match_counts, lvalid, how)
 
-    # output slot j -> (left row, match offset): a searchsorted over the
-    # emit layout, then the gathers of every column
+    # output slot j -> left row (scatter + running max), then its right
+    # row j - offs + lo with one gather of the per-row shift; a left
+    # join's unmatched row shifts its one slot below 0.  Then the gathers
+    # of every column.
     with jax.named_scope("join/expand"):
         j = jnp.arange(out_cap, dtype=jnp.int32)
-        lrow = jnp.searchsorted(cum, j, side="right").astype(jnp.int32)
-        lrow = jnp.clip(lrow, 0, left.capacity - 1)
-        within = j - offs[lrow]
-        matched = within < match_counts[lrow]
-        rrow = jnp.clip(lo[lrow] + within, 0, max(right.capacity - 1, 0))
+        lrow = _slot_rows(offs, out_cap)
+        shift = jnp.where(match_counts > 0, lo - offs, -out_cap)
+        r = j + shift[lrow]
+        matched = r >= 0
+        rrow = jnp.clip(r, 0, max(right.capacity - 1, 0))
 
         cols: dict[str, jax.Array] = {}
         for n in left.names:

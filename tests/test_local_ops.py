@@ -1,4 +1,5 @@
 """Local operator tests against numpy oracles (paper Table 2 operators)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -255,6 +256,99 @@ def test_join_empty_right():
     rt = mk({"k": np.array([], np.int32)})
     out = L.join(lt, rt, left_on=["k"], out_capacity=4)
     assert int(out.nvalid) == 0
+
+
+# name: (match counts of the valid left rows, padding rows, how, out_capacity)
+SLOT_CASES = {
+    "zero_emit_start_mid_end": ([0, 0, 3, 0, 2, 1, 0, 0, 4, 0, 0], 2,
+                                "inner", 16),
+    "all_zero_emit": ([0, 0, 0, 0, 0], 2, "inner", 8),
+    "single_row": ([6], 0, "inner", 8),
+    "truncated": ([2, 0, 5, 3, 0, 4], 1, "inner", 7),
+    "roomy": ([1, 3, 0, 2], 3, "inner", 23),
+    "left_unmatched": ([0, 2, 0, 0, 3, 0], 2, "left", 12),
+}
+
+
+@pytest.mark.parametrize("case", list(SLOT_CASES))
+def test_sortmerge_slot_rows_match_searchsorted(case, rng):
+    """The sortmerge join maps output slot j to its left row with a scatter
+    and a running max; every slot below min(total, out_capacity) gets the
+    row a clipped ``searchsorted(cum, j, side="right")`` gives, and the
+    join emits that row's matches in the right table's row order."""
+    counts, pad, how, out_cap = SLOT_CASES[case]
+    n = len(counts)
+    cap = n + pad
+    mc = np.zeros(cap, np.int32)
+    mc[:n] = counts
+    valid = np.arange(cap) < n
+    emit = np.where(valid & (mc == 0), 1, mc) if how == "left" else mc
+    cum = np.cumsum(emit)
+    offs = cum - emit
+    total = int(cum[-1])
+    n_out = min(total, out_cap)
+    j = np.arange(n_out)
+    want_lrow = np.clip(np.searchsorted(cum, j, side="right"), 0, cap - 1)
+
+    _, got_offs, got_total = L._emit_layout(jnp.asarray(mc),
+                                            jnp.asarray(valid), how)
+    lrow = np.asarray(L._slot_rows(got_offs, out_cap))
+    assert int(got_total) == total
+    np.testing.assert_array_equal(lrow[:n_out], want_lrow)
+    assert ((lrow >= 0) & (lrow < cap)).all()        # padding slots too
+
+    # the same layout through the join: left row i has key i and
+    # counts[i] matches, scattered over the right table
+    rk = rng.permutation(np.repeat(np.arange(n, dtype=np.int32), counts))
+    lt = mk({"k": np.arange(n, dtype=np.int32)}, capacity=cap)
+    rt = mk({"k": rk, "rv": np.arange(len(rk), dtype=np.int32)},
+            capacity=len(rk) + 3)
+    out, overflow = L.join(lt, rt, left_on=["k"], how=how,
+                           out_capacity=out_cap, return_overflow=True,
+                           impl="sortmerge")
+    got = out.to_numpy()
+    assert int(overflow) == max(total - out_cap, 0)
+    np.testing.assert_array_equal(got["k"], want_lrow)
+    want_rv = [np.flatnonzero(rk == r)[s - offs[r]] if mc[r] else INT_NULL
+               for s, r in zip(j, want_lrow)]
+    np.testing.assert_array_equal(got["rv"], np.asarray(want_rv, np.int32))
+
+
+def _loop_carry_shapes(fn, *args) -> list:
+    """Shapes of every ``scan``/``while`` output anywhere in the jaxpr (a
+    loop's outputs are its carry, plus a scan's stacked ys)."""
+    shapes = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name in ("scan", "while"):
+                shapes.extend(v.aval.shape for v in eqn.outvars)
+            for v in eqn.params.values():
+                for x in (v if isinstance(v, (list, tuple)) else (v,)):
+                    if hasattr(x, "jaxpr"):
+                        walk(x.jaxpr)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return shapes
+
+
+@pytest.mark.parametrize("how", ["inner", "left"])
+def test_sortmerge_expand_has_no_per_slot_search(how, rng):
+    """Mapping output slots to left rows is a scatter and a running max:
+    no loop of the sortmerge join carries an ``out_capacity``-sized array
+    (a per-slot binary search would).  The match step's search over the
+    left rows is such a loop, which shows the walk sees loops at all."""
+    out_cap = 203                      # no table capacity divides it
+    lt = mk({"k": rng.integers(0, 10, 30), "lv": np.arange(30)},
+            capacity=40)
+    rt = mk({"k": rng.integers(0, 10, 20), "rv": np.arange(20)},
+            capacity=25)
+    shapes = _loop_carry_shapes(
+        lambda a, b: L.join(a, b, left_on=["k"], how=how,
+                            out_capacity=out_cap, impl="sortmerge"),
+        lt, rt)
+    assert (40,) in shapes, shapes
+    assert not any(out_cap in s for s in shapes), shapes
 
 
 def test_cartesian_product():
