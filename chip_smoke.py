@@ -349,10 +349,11 @@ def phase_serving(sizes: Sizes, seed: int, compiled) -> None:
     p_len = len(r.prompt)
     prefill = jax.jit(M.make_prefill(cfg, None, decode_len=P + G))
     serve = jax.jit(M.make_serve_step(cfg, None))
-    logits, caches = prefill(params, {"tokens": jnp.asarray(r.prompt[None])})
+    logits, caches, _ = prefill(params,
+                                {"tokens": jnp.asarray(r.prompt[None])})
     want = [int(jnp.argmax(logits, -1)[0])]
     for i in range(r.gen_len - 1):
-        logits, caches = serve(params, caches,
+        logits, caches, _ = serve(params, caches,
                                jnp.asarray([[want[-1]]], jnp.int32),
                                jnp.int32(p_len + i))
         want.append(int(jnp.argmax(logits, -1)[0]))

@@ -58,15 +58,34 @@ class ArchConfig:
     top_k: int = 0
     d_expert_ff: int = 0
     n_shared_experts: int = 0
-    # SSM (mamba-1)
+    # MoE held as one chip's share of an expert-parallel layer: the
+    # router keeps ``router_width`` outputs (0: ``n_experts``) and this
+    # layer holds ``n_experts`` of them, from ``expert_offset`` on; a
+    # shared SwiGLU of width ``d_shared_ff`` sits beside them
+    router_width: int = 0
+    expert_offset: int = 0
+    d_shared_ff: int = 0
+    # SSM (mamba-1; mamba-2 when ssm_heads > 0: scalar A per head of
+    # ssm_head_dim channels, B/C shared by ssm_groups, chunked scan)
     ssm_state: int = 0
     ssm_conv: int = 4
     ssm_expand: int = 2
     dt_rank: int = 0
-    # hybrid interleave (jamba): attention every `attn_period` layers,
-    # MoE every `moe_period` layers
-    attn_period: int = 0
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_chunk: int = 256
+    # hybrid interleave: one period of ``layer_types`` ("mamba" /
+    # "attention"), repeated (jamba, granite-4.0-h); MoE every
+    # `moe_period` layers (jamba)
     moe_period: int = 0
+    layer_types: tuple[str, ...] = ()
+    # granite's scalars (the defaults leave the math as without them)
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0  # score scale; 0: 1/sqrt(d_head)
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    use_rope: bool = True              # False: no positional encoding
     # enc-dec
     encoder_layers: int = 0
     enc_len_ratio: int = 4             # encoder frames = seq // ratio
@@ -80,6 +99,11 @@ class ArchConfig:
     @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
+
+    @property
+    def layer_period(self) -> int:
+        """Layers of one scanned period (1: a uniform stack)."""
+        return len(self.layer_types) or 1
 
     @property
     def is_encdec(self) -> bool:
@@ -120,6 +144,11 @@ class ArchConfig:
                 n += hq + 2 * hkv
             if self.is_encdec:            # decoder cross-attention
                 n += d * hq + 2 * d * hkv + hq * d + d
+        elif self.ssm_heads:               # mamba-2 block
+            E, N, K, H = self.d_inner, self.ssm_state, self.ssm_conv, \
+                self.ssm_heads
+            conv = E + 2 * self.ssm_groups * N
+            n += d * (E + conv + H) + K * conv + conv + 3 * H + E + E * d
         else:                              # mamba block
             E, N, K = self.d_inner, self.ssm_state, self.ssm_conv
             dtr = self.dt_rank or max(1, math.ceil(d / 16))
@@ -127,9 +156,10 @@ class ArchConfig:
                 + E * N + E + E * d
         if self._layer_has_moe(i):
             f = self.d_expert_ff or self.d_ff
-            n += d * self.n_experts \
+            n += d * (self.router_width or self.n_experts) \
                 + self.n_experts * 3 * d * f \
-                + self.n_shared_experts * 3 * d * f
+                + self.n_shared_experts * 3 * d * f \
+                + 3 * d * self.d_shared_ff
         elif self.d_ff > 0:
             n += 3 * d * self.d_ff if self.family != "audio" \
                 else 2 * d * self.d_ff
@@ -139,8 +169,9 @@ class ArchConfig:
     def _layer_has_attention(self, i: int) -> bool:
         if self.attention_free:
             return False
-        if self.attn_period > 1:        # jamba: one attn layer per period
-            return (i % self.attn_period) == (self.attn_period - 1)
+        if self.layer_types:
+            return self.layer_types[i % len(self.layer_types)] \
+                == "attention"
         return True
 
     def _layer_has_moe(self, i: int) -> bool:
@@ -199,9 +230,11 @@ ARCH_IDS = (
     "seamless-m4t-large-v2",
     "falcon-mamba-7b",
     "jamba-1.5-large-398b",
+    "granite-4.0-h-small",
 )
 
 _MODULES = {a: a.replace("-", "_").replace(".", "_") for a in ARCH_IDS}
+_MODULES["granite-4.0-h-small"] = "granite_4_h_small"
 _MODULES["unomt"] = "unomt"
 _MODULES["lm100m"] = "lm100m"
 

@@ -21,7 +21,7 @@ CONFIG = ArchConfig(
     n_experts=16,
     top_k=2,
     d_expert_ff=24576,
-    attn_period=8,                # layer 7 of each 8-block is attention
+    layer_types=("mamba",) * 7 + ("attention",),  # 7:1, attention last
     moe_period=2,                 # odd layers are MoE
     ssm_state=16,
     ssm_conv=4,
@@ -34,7 +34,8 @@ CONFIG = ArchConfig(
 
 def reduced() -> ArchConfig:
     return dataclasses.replace(
-        CONFIG, n_layers=4, attn_period=2, moe_period=2, d_model=64,
-        n_heads=4, n_kv_heads=2, d_head=16, d_ff=128, n_experts=8, top_k=2,
-        d_expert_ff=128, vocab=512, ssm_state=8, dt_rank=8,
+        CONFIG, n_layers=4, layer_types=("mamba", "attention"),
+        moe_period=2, d_model=64, n_heads=4, n_kv_heads=2, d_head=16,
+        d_ff=128, n_experts=8, top_k=2, d_expert_ff=128, vocab=512,
+        ssm_state=8, dt_rank=8,
         train=TrainSettings())

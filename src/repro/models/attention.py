@@ -115,8 +115,13 @@ def chunked_attention(q, k, v, *, causal: bool = True, q_chunk: int = 1024,
 
 
 def attention(q, k, v, *, causal: bool = True, impl: str = "xla",
-              q_chunk: int = 1024, k_chunk: int = 1024, policy=None):
-    """Dispatching entry point used by the model layers."""
+              q_chunk: int = 1024, k_chunk: int = 1024, policy=None,
+              scale=None):
+    """Dispatching entry point used by the model layers.  ``scale``
+    multiplies the scores (None: 1/sqrt(d_head))."""
+    if scale is not None and impl in ("pallas", "pallas_interpret"):
+        # the kernel scales by 1/sqrt(d_head): fold the rest into q
+        q = (q * (scale * q.shape[-1] ** 0.5)).astype(q.dtype)
     if impl == "pallas":
         return _pallas_flash(q, k, v, causal=causal, impl="pallas")
     if impl == "pallas_interpret":
@@ -124,12 +129,13 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "xla",
                              bq=min(128, q.shape[2]), bk=min(128, k.shape[2]))
     Sq, Skv = q.shape[2], k.shape[2]
     if Sq <= q_chunk and Skv <= k_chunk:
-        return full_attention(q, k, v, causal=causal, policy=policy)
+        return full_attention(q, k, v, causal=causal, policy=policy,
+                              scale=scale)
     return chunked_attention(q, k, v, causal=causal, q_chunk=q_chunk,
-                             k_chunk=k_chunk, policy=policy)
+                             k_chunk=k_chunk, policy=policy, scale=scale)
 
 
-def decode_attention(q, k_cache, v_cache, cache_len):
+def decode_attention(q, k_cache, v_cache, cache_len, scale=None):
     """Single-token decode: q (B,Hq,1,D) vs cache (B,Hkv,S,D).
 
     Positions ``>= cache_len + 1`` (i.e. beyond the just-written token) are
@@ -142,7 +148,8 @@ def decode_attention(q, k_cache, v_cache, cache_len):
     B, Hq, _, D = q.shape
     Hkv, S = k_cache.shape[1], k_cache.shape[2]
     G = Hq // Hkv
-    qg = q.reshape(B, Hkv, G, D).astype(jnp.float32) * (D ** -0.5)
+    scale = scale if scale is not None else D ** -0.5
+    qg = q.reshape(B, Hkv, G, D).astype(jnp.float32) * scale
     s = jnp.einsum("bhgd,bhkd->bhgk", qg, k_cache.astype(jnp.float32))
     cl = jnp.asarray(cache_len)
     if cl.ndim:                       # per-slot lengths: (B,) -> (B,1,1,1)

@@ -89,6 +89,12 @@ def attn_init(key, cfg, cross: bool = False):
     return p
 
 
+def score_scale(cfg):
+    """The attention score scale: ``attention_multiplier`` where the config
+    gives one (granite), else None (1/sqrt(d_head))."""
+    return cfg.attention_multiplier or None
+
+
 def _split_heads(y, n_heads, d_head):
     B, S, _ = y.shape
     return y.reshape(B, S, n_heads, d_head).transpose(0, 2, 1, 3)
@@ -108,7 +114,7 @@ def attn_apply(p, cfg, x, positions, *, causal=True, kv_x=None,
     if cfg.qk_norm:
         q = rms_norm(p["q_norm"], q, cfg.norm_eps)
         k = rms_norm(p["k_norm"], k, cfg.norm_eps)
-    if use_rope and kv_x is None:
+    if use_rope and cfg.use_rope and kv_x is None:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
     # GQA sharding (EXPERIMENTS.md §Perf iters 1-2): when Hkv doesn't
@@ -138,7 +144,8 @@ def attn_apply(p, cfg, x, positions, *, causal=True, kv_x=None,
         q, k, v = policy.shard_heads(q), policy.shard_kv(k), \
             policy.shard_kv(v)
     o = A.attention(q, k, v, causal=causal, impl=attn_impl,
-                    q_chunk=q_chunk, k_chunk=k_chunk, policy=attn_policy)
+                    q_chunk=q_chunk, k_chunk=k_chunk, policy=attn_policy,
+                    scale=score_scale(cfg))
     B, S = x.shape[:2]
     y = o.transpose(0, 2, 1, 3).reshape(B, S, cfg.n_heads * cfg.d_head)
     return dense(p["wo"], y), (k_cache, v_cache)
@@ -161,8 +168,9 @@ def attn_decode(p, cfg, x, cache, cache_len, *, cross=False, policy=None):
         v_new = _split_heads(dense(p["wv"], x), cfg.n_kv_heads, cfg.d_head)
         if cfg.qk_norm:
             k_new = rms_norm(p["k_norm"], k_new, cfg.norm_eps)
-        q = rope(q, pos, cfg.rope_theta)
-        k_new = rope(k_new, pos, cfg.rope_theta)
+        if cfg.use_rope:
+            q = rope(q, pos, cfg.rope_theta)
+            k_new = rope(k_new, pos, cfg.rope_theta)
         # one-hot scatter write (shard-friendly on a sharded S axis)
         with jax.named_scope("decode/cache_write"):
             S = cache["k"].shape[2]
@@ -180,7 +188,8 @@ def attn_decode(p, cfg, x, cache, cache_len, *, cross=False, policy=None):
     else:
         live_len = cache["k"].shape[2] - 1          # full encoder memory
     with jax.named_scope("decode/attention"):
-        o = A.decode_attention(q, cache["k"], cache["v"], live_len)
+        o = A.decode_attention(q, cache["k"], cache["v"], live_len,
+                               scale=score_scale(cfg))
     B = x.shape[0]
     y = o.transpose(0, 2, 1, 3).reshape(B, 1, cfg.n_heads * cfg.d_head)
     return dense(p["wo"], y), cache

@@ -64,18 +64,21 @@ def _encode(params, cfg, frames, policy, opts):
     """Audio/enc-dec encoder over stub frame embeddings (B,Senc,d)."""
     x = frames.astype(jnp.bfloat16)
     pos = _positions(x.shape[0], x.shape[1])
-    x, _aux, _ = Tf.stack_apply(params["encoder"]["layers"], cfg, x, pos,
-                                policy, opts, causal=False, encoder=True)
+    x, _stats, _ = Tf.stack_apply(params["encoder"]["layers"], cfg, x, pos,
+                                  policy, opts, causal=False, encoder=True)
     return Ly.rms_norm(params["encoder"]["norm"], x, cfg.norm_eps)
 
 
-def backbone(params, cfg, batch, policy, opts, *, want_cache=False):
-    """Embed -> stack -> final norm.  Returns (x, aux, caches, n_prefix).
+def backbone(params, cfg, batch, policy, opts, *, want_cache=False,
+             lengths=None):
+    """Embed -> stack -> final norm.  Returns (x, stats, caches, n_prefix):
+    ``stats`` are the MoE layers' ``aux`` and ``dropped``, summed.
 
-    n_prefix = frontend tokens prepended (vlm) — loss/labels skip them."""
+    n_prefix = frontend tokens prepended (vlm) — loss/labels skip them.
+    ``lengths`` (B,): true lengths of right-padded token rows."""
     tokens = batch["tokens"]
     B, S = tokens.shape
-    x = Ly.embed_lookup(params["embed"], tokens)
+    x = Ly.embed_lookup(params["embed"], tokens) * cfg.embedding_multiplier
     n_prefix = 0
     if cfg.frontend == "vision":
         patches = batch["patch_embeds"].astype(x.dtype)   # (B, P, d)
@@ -87,11 +90,14 @@ def backbone(params, cfg, batch, policy, opts, *, want_cache=False):
     if cfg.is_encdec:
         enc_out = _encode(params, cfg, batch["frames"], policy, opts)
     pos = _positions(B, x.shape[1])
-    x, aux, caches = Tf.stack_apply(params["layers"], cfg, x, pos, policy,
-                                    opts, causal=True, enc_out=enc_out,
-                                    want_cache=want_cache)
+    if lengths is not None:
+        lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32) + n_prefix,
+                                   (B,))
+    x, stats, caches = Tf.stack_apply(params["layers"], cfg, x, pos, policy,
+                                      opts, causal=True, enc_out=enc_out,
+                                      want_cache=want_cache, lengths=lengths)
     x = Ly.rms_norm(params["final_norm"], x, cfg.norm_eps)
-    return x, aux, caches, n_prefix
+    return x, stats, caches, n_prefix
 
 
 # --------------------------------------------------------------------------
@@ -103,6 +109,14 @@ def _head_weight(params, cfg):
     if cfg.tie_embeddings:
         return params["embed"]["embed"].T
     return params["lm_head"]["w"]
+
+
+def logits(params, cfg, x):
+    """x (B,S,d) -> logits fp32 (B,S,V), divided by ``logits_scaling``."""
+    return Ly.logits_out(
+        params.get("lm_head"), x,
+        tied_embed=params["embed"] if cfg.tie_embeddings else None) \
+        / cfg.logits_scaling
 
 
 def ce_loss(params, cfg, x, labels, chunks: int = 1):
@@ -119,7 +133,7 @@ def ce_loss(params, cfg, x, labels, chunks: int = 1):
         xc, yc = inp                                    # (B,c,d), (B,c)
         logits = jax.lax.dot_general(
             xc.astype(jnp.bfloat16), w, (((2,), (0,)), ((), ())),
-            preferred_element_type=F32)                 # (B,c,V)
+            preferred_element_type=F32) / cfg.logits_scaling   # (B,c,V)
         lse = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(
             logits, jnp.maximum(yc, 0)[..., None], axis=-1)[..., 0]
@@ -170,13 +184,14 @@ def make_loss_fn(cfg, policy: Optional[Policy], opts: StackOpts,
     def loss_fn(params, batch):
         if cfg.train.bf16_weight_cast:
             params = _cast_weights_bf16(params, policy)
-        x, aux, _, n_prefix = backbone(params, cfg, batch, policy, opts)
+        x, stats, _, n_prefix = backbone(params, cfg, batch, policy, opts)
         labels = batch["labels"]
         if n_prefix:
             x = x[:, n_prefix:]
         loss = ce_loss(params, cfg, x, labels, cfg.train.loss_seq_chunks)
-        total = loss + aux_coeff * aux
-        return total, {"loss": loss, "moe_aux": aux}
+        total = loss + aux_coeff * stats["aux"]
+        return total, {"loss": loss, "moe_aux": stats["aux"],
+                       "moe_pairs_dropped": stats["dropped"]}
     return loss_fn
 
 
@@ -230,14 +245,16 @@ def make_train_step(cfg, policy: Optional[Policy],
                 g = shard_grads_2d(g)
                 g_sum = jax.tree_util.tree_map(
                     lambda a, b: a + b.astype(F32), g_sum, g)
-                return (g_sum, l_sum + l, a_sum + met["moe_aux"]), None
+                return (g_sum, l_sum + l, a_sum + met["moe_aux"],
+                        d_sum + met["moe_pairs_dropped"]), None
 
-            (g_sum, l_sum, a_sum), _ = jax.lax.scan(
-                acc, (zero_g, jnp.zeros((), F32), jnp.zeros((), F32)),
-                micro)
+            (g_sum, l_sum, a_sum, d_sum), _ = jax.lax.scan(
+                acc, (zero_g, jnp.zeros((), F32), jnp.zeros((), F32),
+                      jnp.zeros((), jnp.int32)), micro)
             grads = jax.tree_util.tree_map(lambda g: g / n_micro, g_sum)
             loss = l_sum / n_micro
-            metrics = {"loss": loss, "moe_aux": a_sum / n_micro}
+            metrics = {"loss": loss, "moe_aux": a_sum / n_micro,
+                       "moe_pairs_dropped": d_sum}
         params, opt_state, opt_metrics = adamw.update(
             params, grads, opt_state, opt_cfg)
         metrics = dict(metrics, **opt_metrics)
@@ -256,19 +273,17 @@ def make_prefill(cfg, policy: Optional[Policy], *, decode_len: int,
     opts = opts_from_cfg(cfg, decode_len=decode_len, attn_impl=attn_impl)
 
     def prefill(params, batch):
-        x, _aux, caches, _ = backbone(params, cfg, batch, policy, opts,
-                                      want_cache=True)
-        logits = Ly.logits_out(
-            params.get("lm_head"), x[:, -1:],
-            tied_embed=params["embed"] if cfg.tie_embeddings else None)
-        return logits[:, 0], caches
+        x, stats, caches, _ = backbone(params, cfg, batch, policy, opts,
+                                       want_cache=True)
+        return logits(params, cfg, x[:, -1:])[:, 0], caches, \
+            {"moe_pairs_dropped": stats["dropped"]}
     return prefill
 
 
 def make_serve_step(cfg, policy: Optional[Policy], *,
                     attn_impl: str = "xla"):
     """One decode step: (params, caches, tokens (B,1), cache_len) ->
-    (logits (B,V), new caches).
+    (logits (B,V), new caches, stats) — ``stack_decode``'s stats.
 
     ``cache_len`` is a scalar (the one-shot serve loop: whole batch at the
     same position) or a ``(B,)`` vector of per-slot positions (the serving
@@ -277,15 +292,12 @@ def make_serve_step(cfg, policy: Optional[Policy], *,
     opts = opts_from_cfg(cfg, attn_impl=attn_impl)
 
     def serve_step(params, caches, tokens, cache_len):
-        x = Ly.embed_lookup(params["embed"], tokens)      # (B,1,d)
-        enc_dummy = None
-        x, new_caches = Tf.stack_decode(params["layers"], cfg, x, caches,
-                                        cache_len, policy, opts)
+        x = Ly.embed_lookup(params["embed"], tokens) \
+            * cfg.embedding_multiplier                    # (B,1,d)
+        x, new_caches, stats = Tf.stack_decode(
+            params["layers"], cfg, x, caches, cache_len, policy, opts)
         x = Ly.rms_norm(params["final_norm"], x, cfg.norm_eps)
-        logits = Ly.logits_out(
-            params.get("lm_head"), x,
-            tied_embed=params["embed"] if cfg.tie_embeddings else None)
-        return logits[:, 0], new_caches
+        return logits(params, cfg, x)[:, 0], new_caches, stats
     return serve_step
 
 
@@ -293,27 +305,28 @@ def make_slot_prefill(cfg, policy: Optional[Policy], *, decode_len: int,
                       attn_impl: str = "xla"):
     """Prefill for one continuous-batching slot refill.
 
-    ``(params, batch, length) -> (logits (B,V), caches)`` where
+    ``(params, batch, length) -> (logits (B,V), caches, stats)`` where
     ``batch['tokens']`` is a fixed-shape *right-padded* prompt ``(B,P)``
     and ``length`` is the true prompt length: logits are taken at
     position ``length - 1`` (the last real token — it attends only to
     real positions under the causal mask) instead of the padded end.
     Padding rows land in cache positions ``>= length`` but stay masked at
     decode (``decode_attention`` masks ``> cache_len``) and are
-    overwritten token by token as the slot generates.  One fixed padded
+    overwritten token by token as the slot generates; SSM layers take
+    their state at ``length`` (the padding leaves it unchanged).
+    ``stats``: the prefill's ``moe_pairs_dropped``.  One fixed padded
     shape = one jit trace for every prompt length (heterogeneous request
     sizes all hit the cached executable)."""
     opts = opts_from_cfg(cfg, decode_len=decode_len, attn_impl=attn_impl)
 
     def slot_prefill(params, batch, length):
-        x, _aux, caches, n_prefix = backbone(params, cfg, batch, policy,
-                                             opts, want_cache=True)
+        x, stats, caches, n_prefix = backbone(params, cfg, batch, policy,
+                                              opts, want_cache=True,
+                                              lengths=length)
         idx = jnp.asarray(n_prefix + length - 1, jnp.int32)
         xl = jax.lax.dynamic_slice_in_dim(x, idx, 1, axis=1)
-        logits = Ly.logits_out(
-            params.get("lm_head"), xl,
-            tied_embed=params["embed"] if cfg.tie_embeddings else None)
-        return logits[:, 0], caches
+        return logits(params, cfg, xl)[:, 0], caches, \
+            {"moe_pairs_dropped": stats["dropped"]}
     return slot_prefill
 
 
@@ -338,7 +351,7 @@ def cache_struct(cfg, batch_size: int, decode_len: int,
                  enc_len: int = 0):
     """Abstract (ShapeDtypeStruct) cache pytree matching stack_apply's
     stacked layout."""
-    per = cfg.attn_period if cfg.attn_period > 1 else 1
+    per = cfg.layer_period
     n_groups = cfg.n_layers // per
     B = batch_size
 
@@ -349,6 +362,12 @@ def cache_struct(cfg, batch_size: int, decode_len: int,
             kv = (B, cfg.n_kv_heads, decode_len, cfg.d_head)
             c["k"] = jax.ShapeDtypeStruct(kv, jnp.bfloat16)
             c["v"] = jax.ShapeDtypeStruct(kv, jnp.bfloat16)
+        elif cfg.ssm_heads:          # mamba-2: heads x d_head x d_state
+            conv = cfg.d_inner + 2 * cfg.ssm_groups * cfg.ssm_state
+            c["conv"] = jax.ShapeDtypeStruct(
+                (B, cfg.ssm_conv - 1, conv), F32)
+            c["ssm"] = jax.ShapeDtypeStruct(
+                (B, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), F32)
         else:
             c["conv"] = jax.ShapeDtypeStruct(
                 (B, cfg.ssm_conv - 1, cfg.d_inner), F32)

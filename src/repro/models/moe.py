@@ -9,8 +9,11 @@ the paper's "distributed operator = communication + local operator"
 recipe (DESIGN.md §2).
 
 Three paths:
-* ``moe_dense``   — compute-all-experts fallback (smoke tests, 1 device,
-  or expert counts indivisible by the model axis, e.g. granite's 40);
+* ``moe_dense``   — one device: every expert it holds over every token,
+  gated (smoke tests, 1 device, expert counts indivisible by the model
+  axis, e.g. granite's 40, and one device's share of an expert-parallel
+  layer, ``cfg.router_width`` set: it routes over every expert and
+  computes the held ones);
 * ``moe_shuffle`` — shard_map EP dispatch for train/prefill (seq sharded
   over the model axis inside the block).  Only the token payload crosses
   the wire (bf16); routing metadata stays local because the tiled
@@ -22,6 +25,10 @@ Three paths:
 
 Uneven expert counts are parameter-padded to a multiple of 16
 (``cfg.n_experts`` stays the routing width; pads receive no tokens).
+
+Every path reports the routed pairs it dropped (``moe_pairs_dropped``):
+capacity is counted, never silent.  A shared expert (``d_shared_ff``) is
+added once, beside whichever path ran.
 """
 from __future__ import annotations
 
@@ -48,15 +55,19 @@ def moe_init(key, cfg):
     d = cfg.d_model
     E = n_experts_padded(cfg)
     f = cfg.d_expert_ff or cfg.d_ff
-    ks = jax.random.split(key, 4)
+    ks = jax.random.split(key, 5)
     std = Ly.INIT_STD
-    return {
-        "router": jax.random.normal(ks[0], (d, cfg.n_experts), F32) * std,
+    width = cfg.router_width or cfg.n_experts
+    p = {
+        "router": jax.random.normal(ks[0], (d, width), F32) * std,
         "e_gate": jax.random.normal(ks[1], (E, d, f), F32) * std,
         "e_up": jax.random.normal(ks[2], (E, d, f), F32) * std,
         "e_down": jax.random.normal(ks[3], (E, f, d), F32)
         * (std / math.sqrt(2 * cfg.n_layers)),
     }
+    if cfg.d_shared_ff:
+        p["shared"] = Ly.swiglu_init(ks[4], d, cfg.d_shared_ff, cfg.n_layers)
+    return p
 
 
 def _route(router, x2d, top_k: int):
@@ -82,24 +93,40 @@ def _expert_ffn(eg, eu, ed, xb):
 
 
 # --------------------------------------------------------------------------
-# dense fallback
+# one device: every expert it holds, over every token
 # --------------------------------------------------------------------------
 
 
 def moe_dense(p, cfg, x):
+    """The ``cfg.n_experts`` experts held here, from ``cfg.expert_offset``,
+    of a router ``cfg.router_width`` wide (0: it routes over the held
+    experts alone).  x (B,S,d) -> (y, aux, pairs): y is the held experts'
+    part of the layer (pairs routed elsewhere are another device's);
+    ``pairs`` counts the routed pairs each held expert took.
+
+    Every held expert runs over every token, and a gate of 0 weights each
+    pair not routed to it, so nothing is dispatched and nothing drops.
+    The gates scale the hidden activations, and the down projection sums
+    over experts and hidden units in one contraction."""
     B, S, d = x.shape
     E = cfg.n_experts
     x2 = x.reshape(B * S, d)
-    w, ids, aux = _route(p["router"], x2, cfg.top_k)
-    gates = jnp.sum(jax.nn.one_hot(ids, E, dtype=F32) * w[..., None],
-                    axis=1)                                   # (T, E)
-    bf = jnp.bfloat16
-    eg, eu, ed = (p["e_gate"][:E], p["e_up"][:E], p["e_down"][:E])
-    h = jnp.einsum("td,edf->tef", x2.astype(bf), eg.astype(bf))
-    u = jnp.einsum("td,edf->tef", x2.astype(bf), eu.astype(bf))
-    o = jnp.einsum("tef,efd->ted", jax.nn.silu(h) * u, ed.astype(bf))
-    y = jnp.einsum("ted,te->td", o.astype(F32), gates)
-    return y.reshape(B, S, d).astype(x.dtype), aux
+    with jax.named_scope("moe/route"):
+        w, ids, aux = _route(p["router"], x2, cfg.top_k)
+        hot = jax.nn.one_hot(ids - cfg.expert_offset, E, dtype=F32)
+        gates = jnp.einsum("tke,tk->te", hot, w)
+        pairs = jnp.sum(hot, axis=(0, 1)).astype(jnp.int32)
+    with jax.named_scope("moe/experts"):
+        bf = jnp.bfloat16
+        eg, eu, ed = (p["e_gate"][:E], p["e_up"][:E], p["e_down"][:E])
+        h = jnp.einsum("td,edf->tef", x2.astype(bf), eg.astype(bf),
+                       preferred_element_type=F32)
+        u = jnp.einsum("td,edf->tef", x2.astype(bf), eu.astype(bf),
+                       preferred_element_type=F32)
+        a = (jax.nn.silu(h) * u * gates[..., None]).astype(bf)
+        y = jnp.einsum("tef,efd->td", a, ed.astype(bf),
+                       preferred_element_type=F32)
+    return y.reshape(B, S, d).astype(x.dtype), aux, pairs
 
 
 # --------------------------------------------------------------------------
@@ -123,7 +150,8 @@ def moe_shuffle(p, cfg, x, policy, capacity_factor: float = 1.25):
     E_pad = n_experts_padded(cfg)
     if world_m == 1 or E_pad % world_m != 0 \
             or x.shape[1] % world_m != 0:
-        return moe_dense(p, cfg, x)
+        y, aux, _ = moe_dense(p, cfg, x)
+        return y, aux, jnp.zeros((), jnp.int32)
     baxes = _batch_axes_for(policy, x.shape[0])
     batch_spec = P(baxes, maxis, None)
     aux_spec = P(baxes, maxis)
@@ -175,14 +203,14 @@ def moe_shuffle(p, cfg, x, policy, capacity_factor: float = 1.25):
     # numerics-identical (the expert GEMMs cast at use anyway)
     cast = (lambda w: w.astype(jnp.bfloat16)) \
         if cfg.train.bf16_weight_cast else (lambda w: w)
-    y, aux, _dropped = shard_map(
+    y, aux, dropped = shard_map(
         local, mesh=mesh,
         in_specs=(batch_spec, P(), P(maxis, None, None),
                   P(maxis, None, None), P(maxis, None, None)),
         out_specs=(batch_spec, aux_spec, aux_spec),
     )(x, p["router"], cast(p["e_gate"]), cast(p["e_up"]),
       cast(p["e_down"]))
-    return y, jnp.mean(aux)
+    return y, jnp.mean(aux), jnp.sum(dropped)
 
 
 # --------------------------------------------------------------------------
@@ -197,7 +225,8 @@ def moe_decode(p, cfg, x, policy, capacity_factor: float = 4.0):
     E = cfg.n_experts
     E_pad = n_experts_padded(cfg)
     if world_m == 1 or E_pad % world_m != 0:
-        return moe_dense(p, cfg, x)
+        y, aux, _ = moe_dense(p, cfg, x)
+        return y, aux, jnp.zeros((), jnp.int32)
     baxes = _batch_axes_for(policy, x.shape[0])
     batch_spec = P(baxes, None, None)
     aux_spec = P(baxes)
@@ -228,21 +257,36 @@ def moe_decode(p, cfg, x, policy, capacity_factor: float = 4.0):
         contrib = g * (wf * ok)[:, None]
         part = jnp.zeros((T, d), F32).at[src].add(contrib)
         y = jax.lax.psum(part, maxis)
-        return y.reshape(b, s, d).astype(x_loc.dtype), aux[None]
+        dropped = jax.lax.psum(jnp.sum(mine & ~ok, dtype=jnp.int32), maxis)
+        return y.reshape(b, s, d).astype(x_loc.dtype), aux[None], \
+            dropped[None]
 
-    y, aux = shard_map(
+    y, aux, dropped = shard_map(
         local, mesh=mesh,
         in_specs=(batch_spec, P(), P(maxis, None, None),
                   P(maxis, None, None), P(maxis, None, None)),
-        out_specs=(batch_spec, aux_spec),
+        out_specs=(batch_spec, aux_spec, aux_spec),
     )(x, p["router"], p["e_gate"], p["e_up"], p["e_down"])
-    return y, jnp.mean(aux)
+    return y, jnp.mean(aux), dropped[0]
 
 
 def moe_apply(p, cfg, x, policy=None, *, decode: bool = False,
               capacity_factor: float = 1.25):
+    """x (B,S,d) -> (y, stats): ``stats`` holds the load-balancing
+    ``aux``, ``dropped`` (routed pairs no expert computed) and, on one
+    device, ``pairs`` per held expert."""
+    stats = {}
     if policy is None or policy.mesh is None:
-        return moe_dense(p, cfg, x)
-    if decode or x.shape[1] < policy.mesh.shape[policy.model_axis]:
-        return moe_decode(p, cfg, x, policy)
-    return moe_shuffle(p, cfg, x, policy, capacity_factor)
+        y, aux, stats["pairs"] = moe_dense(p, cfg, x)
+        dropped = jnp.zeros((), jnp.int32)
+    elif cfg.router_width not in (0, cfg.n_experts):
+        raise NotImplementedError("a mesh shards every expert; a config "
+                                  "that holds a share runs without one")
+    elif decode or x.shape[1] < policy.mesh.shape[policy.model_axis]:
+        y, aux, dropped = moe_decode(p, cfg, x, policy)
+    else:
+        y, aux, dropped = moe_shuffle(p, cfg, x, policy, capacity_factor)
+    if "shared" in p:
+        y = y + Ly.swiglu(p["shared"], x)
+    stats.update(aux=aux, dropped=dropped)
+    return y, stats

@@ -27,11 +27,17 @@ admission -> feature fetch -> slot prefill -> continuous-batching decode
 * **Decode** (``models.model.make_serve_step`` with per-slot cache
   lengths): ONE cached jitted ``serve_step`` with donated cache buffers
   drives the whole fixed-shape batch; as sequences finish, their slots
-  are refilled from the queue immediately (continuous batching — the
-  batch never drains to a barrier).
+  are refilled from the queue, one request a step (continuous batching —
+  the batch never drains to a barrier, and no token waits behind more
+  than one prefill).
 
 Every stage reports into :class:`~repro.serving.metrics.ServingMetrics`
 (queue depth, rejects, slot occupancy, tokens/s inputs, latency series).
+One code path serves dense, Mamba-1, Mamba-2 and MoE configs: each slot's
+cache holds what its layers need (KV, or conv and SSM state taken at the
+prompt's true length), and the MoE layers' dropped pairs are counted
+(``moe_pairs_dropped``); on one device, each decode step's pairs per
+held expert go on its ``serve/decode`` span as ``expert_pairs``.
 """
 from __future__ import annotations
 
@@ -293,15 +299,16 @@ class ServingEngine:
                                       attn_impl=attn_impl)
 
         def prefill_body(params, batch, length):
-            logits, caches = prefill(params, batch, length)
-            return jnp.argmax(logits, -1).astype(jnp.int32), caches
+            logits, caches, stats = prefill(params, batch, length)
+            return jnp.argmax(logits, -1).astype(jnp.int32), caches, stats
 
         serve = M.make_serve_step(cfg, policy, attn_impl=attn_impl)
 
         def decode_body(params, caches, tokens, cache_lens):
-            logits, new_caches = serve(params, caches, tokens, cache_lens)
+            logits, new_caches, stats = serve(params, caches, tokens,
+                                              cache_lens)
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
-            return nxt, new_caches
+            return nxt, new_caches, stats
 
         self._prefill = jax.jit(prefill_body)
         self._insert = jax.jit(M.write_cache_slot, donate_argnums=(0,))
@@ -359,17 +366,18 @@ class ServingEngine:
 
     # --------------------------------------------------------------- refill
     def _refill(self) -> None:
-        free = self.batch.free()
-        n = min(len(free), len(self.queue))
-        if n == 0:
+        """Admit at most one queued request a step.  Every prefill stops
+        all running slots; admitting every queued request at once would
+        stack their prefills into one inter-token gap, so a burst of
+        arrivals would set the gap tail.  One a step keeps any gap to one
+        prefill and one decode."""
+        if not (self.batch.free() and self.queue):
             return
-        with trace.span("serve/refill", n=n):
-            now = self.clock()
-            reqs = [self.queue.pop() for _ in range(n)]
-            for r in reqs:                  # admitted: taken off the queue
-                r.t_admit = now
-                self.metrics.observe("queue_wait", now - r.t_submit)
-            for r in self._fetch_features(reqs):
+        with trace.span("serve/refill"):
+            r = self.queue.pop()
+            r.t_admit = self.clock()        # admitted: taken off the queue
+            self.metrics.observe("queue_wait", r.t_admit - r.t_submit)
+            for r in self._fetch_features([r]):
                 self._prefill_into(r, self.batch.free()[0])
         self.metrics.gauge("slot_occupancy", self.batch.occupancy)
 
@@ -382,11 +390,13 @@ class ServingEngine:
                         positions=self.prompt_capacity):
             padded = np.zeros((1, self.prompt_capacity), np.int32)
             padded[0, :prompt_len] = r.prompt
-            first, one = self._prefill(
+            first, one, stats = self._prefill(
                 self.params, {"tokens": jnp.asarray(padded)},
                 jnp.int32(prompt_len))
             self.caches = self._insert(self.caches, one, jnp.int32(slot))
+            first, stats = jax.device_get((first, stats))
             first_tok = int(first[0])
+        self.metrics.inc("moe_pairs_dropped", stats["moe_pairs_dropped"])
         r.t_first = self.clock()
         r.status = "active"
         r.out_tokens.append(first_tok)
@@ -420,13 +430,16 @@ class ServingEngine:
         return done
 
     def _decode_step(self, n_active: int) -> None:
-        with trace.span("serve/decode", active=n_active):
-            nxt, self.caches = self._decode(
+        with trace.span("serve/decode", active=n_active) as sp:
+            nxt, self.caches, stats = self._decode(
                 self.params, self.caches,
                 jnp.asarray(self.batch.tokens),
                 jnp.asarray(self.batch.cache_lens))
             with trace.span("serve/device_wait"):
-                nxt = np.asarray(nxt)
+                nxt, stats = jax.device_get((nxt, stats))
+            if "expert_pairs" in stats:
+                sp.attrs["expert_pairs"] = stats["expert_pairs"].tolist()
+            self.metrics.inc("moe_pairs_dropped", stats["moe_pairs_dropped"])
             self.metrics.inc("decode_steps")
             self.metrics.inc("tokens_generated", n_active)
             finished = self.batch.advance(

@@ -10,7 +10,9 @@ registry so the millions-of-users story is *measurable* —
   no feature row — terminal, counted), ``prefills``, ``decode_steps``,
   ``tokens_generated``, ``feature_rows`` (feature-table rows joined onto
   requests), ``feature_dropped`` (rows lost in the feature-fetch
-  shuffle/join slabs — must stay 0 when sized right);
+  shuffle/join slabs — must stay 0 when sized right),
+  ``moe_pairs_dropped`` (routed (token, expert) pairs that no expert
+  computed, in prefill and decode — 0 on a dropless path);
 * **gauges** (last + max): ``queue_depth``, ``slot_occupancy``;
 * **series** (observations in seconds): ``latency`` (submit -> done),
   ``ttft`` (submit -> first token), ``queue_wait`` (submit -> taken off

@@ -80,13 +80,13 @@ def test_reduced_prefill_then_decode(arch):
     serve = jax.jit(M.make_serve_step(cfg, None))
     batch = _batch(cfg)
     batch.pop("labels")
-    logits, caches = prefill(params, batch)
+    logits, caches, _ = prefill(params, batch)
     V = cfg.padded_vocab()
     assert logits.shape == (B, V)
     assert np.isfinite(np.asarray(logits, np.float32)).all()
     tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
     for i in range(2):
-        logits, caches = serve(params, caches, tok, jnp.int32(S + i))
+        logits, caches, _ = serve(params, caches, tok, jnp.int32(S + i))
         assert logits.shape == (B, V)
         assert np.isfinite(np.asarray(logits, np.float32)).all()
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
@@ -112,8 +112,8 @@ def test_decode_matches_teacher_forcing_dense():
     decode_len = 12
     prefill = M.make_prefill(cfg, None, decode_len=decode_len)
     serve = M.make_serve_step(cfg, None)
-    _, caches = prefill(params, {"tokens": toks[:, :7]})
-    step_logits, _ = serve(params, caches, toks[:, 7:8], jnp.int32(7))
+    _, caches, _ = prefill(params, {"tokens": toks[:, :7]})
+    step_logits, _, _ = serve(params, caches, toks[:, 7:8], jnp.int32(7))
     np.testing.assert_allclose(np.asarray(step_logits),
                                np.asarray(full_logits[:, 7]),
                                rtol=3e-2, atol=3e-2)
@@ -132,8 +132,8 @@ def test_decode_matches_teacher_forcing_ssm():
         tied_embed=params["embed"] if cfg.tie_embeddings else None)
     prefill = M.make_prefill(cfg, None, decode_len=12)
     serve = M.make_serve_step(cfg, None)
-    _, caches = prefill(params, {"tokens": toks[:, :7]})
-    step_logits, _ = serve(params, caches, toks[:, 7:8], jnp.int32(7))
+    _, caches, _ = prefill(params, {"tokens": toks[:, :7]})
+    step_logits, _, _ = serve(params, caches, toks[:, 7:8], jnp.int32(7))
     np.testing.assert_allclose(np.asarray(step_logits),
                                np.asarray(full_logits[:, 7]),
                                rtol=3e-2, atol=3e-2)
@@ -154,6 +154,7 @@ def test_full_config_matches_assignment(arch):
         "seamless-m4t-large-v2": (24, 1024, 16, 16, 8192, 256206),
         "falcon-mamba-7b": (64, 4096, 0, 0, 0, 65024),
         "jamba-1.5-large-398b": (72, 8192, 64, 8, 24576, 65536),
+        "granite-4.0-h-small": (40, 4096, 32, 8, 0, 100352),
     }[arch]
     assert (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
             cfg.d_ff, cfg.vocab) == spec

@@ -34,7 +34,7 @@ def test_moe_dense_shapes_finite():
     p = Moe.moe_init(jax.random.PRNGKey(2), cfg)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 8, cfg.d_model),
                           jnp.float32)
-    y, aux = Moe.moe_dense(p, cfg, x)
+    y, aux, _ = Moe.moe_dense(p, cfg, x)
     assert y.shape == x.shape
     assert np.isfinite(np.asarray(y, np.float32)).all()
     assert np.isfinite(float(aux))
@@ -45,7 +45,7 @@ def test_moe_apply_without_mesh_falls_back_to_dense():
     p = Moe.moe_init(jax.random.PRNGKey(4), cfg)
     x = jax.random.normal(jax.random.PRNGKey(5), (1, 8, cfg.d_model))
     y1, a1 = Moe.moe_apply(p, cfg, x, policy=None)
-    y2, a2 = Moe.moe_dense(p, cfg, x)
+    y2, a2, _ = Moe.moe_dense(p, cfg, x)
     np.testing.assert_allclose(np.asarray(y1, np.float32),
                                np.asarray(y2, np.float32), rtol=1e-5)
 
@@ -70,7 +70,7 @@ def test_moe_grad_flows_to_router():
     x = jax.random.normal(jax.random.PRNGKey(9), (1, 8, cfg.d_model))
 
     def loss(p):
-        y, aux = Moe.moe_dense(p, cfg, x)
+        y, aux, _ = Moe.moe_dense(p, cfg, x)
         return jnp.sum(y.astype(jnp.float32) ** 2) + 0.01 * aux
 
     g = jax.grad(loss)(p)

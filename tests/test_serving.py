@@ -223,6 +223,18 @@ def test_engine_spans_one_decode_per_step(served):
     assert all(h >= 0 for h in host)
 
 
+def test_engine_admits_at_most_one_prefill_a_step(served):
+    """Both slots were free with the queue full: the engine still admits
+    one request a step, so no step (no inter-token gap) holds two
+    prefills."""
+    *_, spans = served
+    for s in spans["serve/step"]:
+        inside = [p for p in spans["serve/prefill"]
+                  if s.start <= p.start and p.end <= s.end]
+        assert len(inside) <= 1
+    assert len(spans["serve/prefill"]) > 1
+
+
 def test_engine_queue_wait_ends_before_the_first_token(served):
     _, _, _, reqs, _, done, _ = served
     for r in done:
@@ -254,14 +266,14 @@ def test_engine_matches_oneshot_greedy_decode():
         # reference: exact-length one-shot decode (launch/serve's loop)
         prefill = jax.jit(M.make_prefill(cfg, None, decode_len=P + G))
         serve = jax.jit(M.make_serve_step(cfg, None))
-        logits, caches = prefill(params, {"tokens": jnp.asarray(
+        logits, caches, _ = prefill(params, {"tokens": jnp.asarray(
             prompt[None])})
         tok = int(jnp.argmax(logits, -1)[0])
         want = [tok]
         for i in range(G - 1):
-            logits, caches = serve(params, caches,
-                                   jnp.asarray([[tok]], jnp.int32),
-                                   jnp.int32(p_len + i))
+            logits, caches, _ = serve(params, caches,
+                                      jnp.asarray([[tok]], jnp.int32),
+                                      jnp.int32(p_len + i))
             tok = int(jnp.argmax(logits, -1)[0])
             want.append(tok)
 
@@ -271,6 +283,45 @@ def test_engine_matches_oneshot_greedy_decode():
         assert eng.submit(req)
         done = eng.run_until_drained()
         assert done[0].out_tokens == want, f"p_len={p_len}"
+
+
+def engine_matches_oneshot(cfg, params):
+    """Two requests with prompts below ``prompt_capacity`` through the
+    engine's padded slot prefill and decode emit the one-shot path's
+    greedy tokens; returns the engine."""
+    P, G = 12, 5
+    rng = np.random.default_rng(5)
+    prefill = jax.jit(M.make_prefill(cfg, None, decode_len=P + G))
+    serve = jax.jit(M.make_serve_step(cfg, None))
+    eng = ServingEngine(cfg, params, slots=2, prompt_capacity=P,
+                        gen_capacity=G, queue_capacity=4)
+    for i, p_len in enumerate((7, 3)):
+        prompt = rng.integers(0, cfg.vocab, p_len).astype(np.int32)
+        logits, caches, _ = prefill(params, {"tokens": jnp.asarray(
+            prompt[None])})
+        want = [int(jnp.argmax(logits, -1)[0])]
+        for j in range(G - 1):
+            logits, caches, _ = serve(params, caches,
+                                      jnp.asarray([[want[-1]]], jnp.int32),
+                                      jnp.int32(p_len + j))
+            want.append(int(jnp.argmax(logits, -1)[0]))
+        assert eng.submit(Request(req_id=i, prompt=prompt, gen_len=G))
+        done = eng.run_until_drained()
+        assert done[0].out_tokens == want, f"p_len={p_len}"
+    return eng
+
+
+@pytest.mark.parametrize("arch", ["falcon-mamba-7b", "jamba-1.5-large-398b"])
+def test_engine_matches_oneshot_greedy_decode_ssm(arch):
+    """Mamba-1 configs: the padded slot prefill takes the SSM state at the
+    prompt's true length.  The layers' weights are at 4x the init's gain,
+    at which the state decides the greedy tokens (a state taken at the
+    padded end changes them)."""
+    cfg = get_reduced(arch)
+    params = jax.tree_util.tree_map(
+        lambda a: a * 4 if a.ndim >= 3 else a,
+        M.init_params(jax.random.PRNGKey(0), cfg))
+    engine_matches_oneshot(cfg, params)
 
 
 def test_engine_rejects_nonlm_config():

@@ -118,3 +118,39 @@ def test_flash_attention_compiles_at_lm100m_width(one_chip):
     _compile(lambda q, k, v: fa_ops.flash_attention(q, k, v, causal=True,
                                                     impl="pallas"),
              one_chip, qkv, qkv, qkv)
+
+
+def test_held_expert_layer_and_mamba2_step_compile(one_chip):
+    """granite-4.0-h-small's decode-step pieces at published widths, for a
+    64-slot batch: the held-expert layer (9 of 72 experts, top-10) runs
+    its held experts over every token with no dispatch (no scatter), and
+    the Mamba-2 step (128 heads x 64 x 128 state) compiles."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import mamba as Mb
+    from repro.models import moe as Moe
+    cfg = dataclasses.replace(get_config("granite-4.0-h-small"), n_layers=10,
+                              n_experts=9)
+
+    def shapes(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16,
+                                           sharding=one_chip), tree)
+    x = jax.ShapeDtypeStruct((64, 1, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    moe_p = shapes(jax.eval_shape(lambda k: Moe.moe_init(k, cfg),
+                                  jax.random.PRNGKey(0)))
+    text = jax.jit(lambda p, x: Moe.moe_apply(p, cfg, x)).lower(
+        moe_p, x).compile().as_text()
+    assert "scatter" not in text
+    m_p = shapes(jax.eval_shape(lambda k: Mb.mamba2_init(k, cfg),
+                                jax.random.PRNGKey(0)))
+    conv = cfg.d_inner + 2 * cfg.ssm_groups * cfg.ssm_state
+    state = {"conv": jax.ShapeDtypeStruct((64, cfg.ssm_conv - 1, conv),
+                                          jnp.float32, sharding=one_chip),
+             "ssm": jax.ShapeDtypeStruct(
+                 (64, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+                 jnp.float32, sharding=one_chip)}
+    jax.jit(lambda p, x, s: Mb.mamba2_step(p, cfg, x, s)).lower(
+        m_p, x, state).compile()
