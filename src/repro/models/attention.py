@@ -135,15 +135,20 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "xla",
                              k_chunk=k_chunk, policy=policy, scale=scale)
 
 
-def decode_attention(q, k_cache, v_cache, cache_len, scale=None):
+def decode_attention(q, k_cache, v_cache, cache_len, scale=None,
+                     k_new=None, v_new=None):
     """Single-token decode: q (B,Hq,1,D) vs cache (B,Hkv,S,D).
 
-    Positions ``>= cache_len + 1`` (i.e. beyond the just-written token) are
-    masked.  ``cache_len`` is a scalar (whole batch at one position) or a
-    ``(B,)`` vector (continuous batching: every slot at its own position —
-    the serving engine's per-slot decode).  Shard-friendly: reductions over
-    the cache S axis lower to (all-)reduces when S is sharded — the
-    flash-decoding pattern falls out of GSPMD automatically.
+    Without ``k_new``/``v_new`` the cache already holds the token at
+    ``cache_len`` and positions ``>= cache_len + 1`` are masked.  With them
+    (B,Hkv,1,D), the cache is scored below ``cache_len`` and the token's
+    own K and V are scored beside it, so the cache need not be written
+    before attention reads it.  ``cache_len`` is a scalar (whole batch at
+    one position) or a ``(B,)`` vector (continuous batching: every slot
+    at its own position — the serving engine's per-slot decode).
+    Shard-friendly: reductions over the cache S axis lower to
+    (all-)reduces when S is sharded — the flash-decoding pattern falls out
+    of GSPMD automatically.
     """
     B, Hq, _, D = q.shape
     Hkv, S = k_cache.shape[1], k_cache.shape[2]
@@ -154,8 +159,18 @@ def decode_attention(q, k_cache, v_cache, cache_len, scale=None):
     cl = jnp.asarray(cache_len)
     if cl.ndim:                       # per-slot lengths: (B,) -> (B,1,1,1)
         cl = cl[:, None, None, None]
-    live = jnp.arange(S)[None, None, None, :] <= cl
-    s = jnp.where(live, s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgk,bhkd->bhgd", p, v_cache.astype(jnp.float32))
+    pos = jnp.arange(S)[None, None, None, :]
+    if k_new is None:
+        s = jnp.where(pos <= cl, s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bhgk,bhkd->bhgd", p, v_cache.astype(jnp.float32))
+        return o.reshape(B, Hq, 1, D).astype(q.dtype)
+    s = jnp.where(pos < cl, s, NEG_INF)
+    s_new = jnp.einsum("bhgd,bhd->bhg", qg,
+                       k_new[:, :, 0].astype(jnp.float32))[..., None]
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), s_new)
+    e, e_new = jnp.exp(s - m), jnp.exp(s_new - m)
+    o = jnp.einsum("bhgk,bhkd->bhgd", e, v_cache.astype(jnp.float32)) \
+        + e_new * v_new[:, :, None, 0].astype(jnp.float32)
+    o = o / (jnp.sum(e, axis=-1, keepdims=True) + e_new)
     return o.reshape(B, Hq, 1, D).astype(q.dtype)

@@ -157,13 +157,25 @@ def attn_decode(p, cfg, x, cache, cache_len, *, cross=False, policy=None):
 
     ``cache_len`` is a scalar or a ``(B,)`` vector of per-slot positions
     (continuous batching: each sequence in the batch decodes at its own
-    length — the write, rope phase, and mask are all per-slot)."""
+    length — the rope phase and the mask are per-slot).  The cache is only
+    read: attention takes the positions below ``cache_len`` from it and
+    the new token's K and V as they are computed.  Returns ``(y, new)``
+    where ``new`` = {"k", "v"} (B,Hkv,1,D) is the token's K and V for the
+    caller to write at ``cache_len``; {} for cross attention."""
     q = _split_heads(dense(p["wq"], x), cfg.n_heads, cfg.d_head)
     if cfg.qk_norm:
         q = rms_norm(p["q_norm"], q, cfg.norm_eps)
-    if not cross:
+    if cross:
+        with jax.named_scope("decode/attention"):
+            # the full encoder memory
+            o = A.decode_attention(q, cache["k"], cache["v"],
+                                   cache["k"].shape[2] - 1,
+                                   scale=score_scale(cfg))
+        new = {}
+    else:
         cl = jnp.asarray(cache_len)
         pos = cl if cl.ndim == 0 else cl[:, None]        # rope: (B,1)
+        dt = cache["k"].dtype
         k_new = _split_heads(dense(p["wk"], x), cfg.n_kv_heads, cfg.d_head)
         v_new = _split_heads(dense(p["wv"], x), cfg.n_kv_heads, cfg.d_head)
         if cfg.qk_norm:
@@ -171,28 +183,14 @@ def attn_decode(p, cfg, x, cache, cache_len, *, cross=False, policy=None):
         if cfg.use_rope:
             q = rope(q, pos, cfg.rope_theta)
             k_new = rope(k_new, pos, cfg.rope_theta)
-        # one-hot scatter write (shard-friendly on a sharded S axis)
-        with jax.named_scope("decode/cache_write"):
-            S = cache["k"].shape[2]
-            if cl.ndim == 0:
-                onehot = (jnp.arange(S) == cl).astype(cache["k"].dtype)
-                oh = onehot[None, None, :, None]
-            else:                   # per-slot write position: (B,1,S,1)
-                onehot = (jnp.arange(S)[None, :] == cl[:, None]) \
-                    .astype(cache["k"].dtype)
-                oh = onehot[:, None, :, None]
-            dt = cache["k"].dtype
-            cache = {"k": cache["k"] * (1 - oh) + k_new.astype(dt) * oh,
-                     "v": cache["v"] * (1 - oh) + v_new.astype(dt) * oh}
-        live_len = cache_len
-    else:
-        live_len = cache["k"].shape[2] - 1          # full encoder memory
-    with jax.named_scope("decode/attention"):
-        o = A.decode_attention(q, cache["k"], cache["v"], live_len,
-                               scale=score_scale(cfg))
+        new = {"k": k_new.astype(dt), "v": v_new.astype(dt)}
+        with jax.named_scope("decode/attention"):
+            o = A.decode_attention(q, cache["k"], cache["v"], cache_len,
+                                   scale=score_scale(cfg),
+                                   k_new=new["k"], v_new=new["v"])
     B = x.shape[0]
     y = o.transpose(0, 2, 1, 3).reshape(B, 1, cfg.n_heads * cfg.d_head)
-    return dense(p["wo"], y), cache
+    return dense(p["wo"], y), new
 
 
 # --------------------------------------------------------------------------

@@ -181,11 +181,12 @@ def _cache_pad(k, decode_len: int):
 
 
 def layer_decode(p, cfg, x, cache, cache_len, policy, opts):
-    """One-token decode through one layer; returns (x, new_cache,
-    stats)."""
+    """One-token decode through one layer; the cache is only read.
+    Returns (x, new, stats): ``new`` is what the step writes — the token's
+    K and V (B,Hkv,1,D) for attention, the whole new conv and SSM state
+    for a mamba layer; cross-attention memory is never written."""
     r = cfg.residual_multiplier
     h = Ly.rms_norm(p["ln1"], x, cfg.norm_eps)
-    cache = dict(cache)
     if "attn" in p:
         y, new = Ly.attn_decode(p["attn"], cfg, h,
                                 {"k": cache["k"], "v": cache["v"]},
@@ -194,7 +195,6 @@ def layer_decode(p, cfg, x, cache, cache_len, policy, opts):
         step = Mb.mamba2_step if cfg.ssm_heads else Mb.mamba_step
         y, new = step(p["mamba"], cfg, h, {"conv": cache["conv"],
                                            "ssm": cache["ssm"]})
-    cache.update(new)
     x = x + y * r
     if "cross" in p:
         hc = Ly.rms_norm(p["ln_cross"], x, cfg.norm_eps)
@@ -203,7 +203,7 @@ def layer_decode(p, cfg, x, cache, cache_len, policy, opts):
                                cache_len, cross=True, policy=policy)
         x = x + yc
     x, stats = _apply_ffn(p, cfg, x, policy, opts, decode=True)
-    return x, cache, stats
+    return x, new, stats
 
 
 # --------------------------------------------------------------------------
@@ -268,9 +268,34 @@ def stack_apply(stack_params, cfg, x, positions, policy, opts, *,
     return x, stats, (caches if want_cache else None)
 
 
+def write_kv(cache, new, cache_len):
+    """Write one token's K or V per slot into a stacked cache leaf, in
+    place: ``cache`` (G,B,Hkv,S,D), ``new`` (G,B,Hkv,1,D) of its dtype,
+    slot ``b`` at its own position ``cache_len[b]`` in ``[0, S)``, or every
+    slot at one scalar position.  One ``dynamic_update_slice`` of
+    G x Hkv x D values per slot (of G x B x Hkv x D for a scalar); the rest
+    of the cache is not touched."""
+    cl = jnp.asarray(cache_len, jnp.int32)
+    with jax.named_scope("decode/cache_write"):
+        if cl.ndim == 0:
+            return jax.lax.dynamic_update_slice(cache, new, (0, 0, 0, cl, 0))
+        for b in range(cache.shape[1]):
+            cache = jax.lax.dynamic_update_slice(
+                cache, new[:, b:b + 1], (0, b, 0, cl[b], 0))
+    return cache
+
+
 def stack_decode(stack_params, cfg, x, caches, cache_len, policy, opts):
     """Decode one token through the whole stack; caches are stacked over
     the scan axis exactly as produced by stack_apply(want_cache=True).
+
+    The layer scan only reads the caches.  It emits what the step writes:
+    each attention layer's K and V of the token (n_groups,B,Hkv,1,D), which
+    ``write_kv`` puts into the (donated) stacked leaves at each slot's
+    ``cache_len`` after the scan, and each mamba layer's new state, which
+    replaces its leaf whole.  Cross-attention leaves pass through.  So on
+    one device no op but attention (each layer's K and V read once) touches
+    a whole stacked K or V leaf.
     Returns (x, new_caches, stats): ``moe_pairs_dropped`` summed over the
     layers and, on one device, ``expert_pairs`` (MoE layers, held
     experts)."""
@@ -279,9 +304,9 @@ def stack_decode(stack_params, cfg, x, caches, cache_len, policy, opts):
     def body(carry, inp):
         x, dropped = carry
         p_layer, cache = inp
-        new_cache, pairs = {}, []
+        written, pairs = {}, []
         for name in _sublayers(per):
-            x, nc, st = layer_decode(
+            x, new, st = layer_decode(
                 p_layer if name is None else p_layer[name], cfg, x,
                 cache if name is None else cache[name], cache_len, policy,
                 opts)
@@ -289,13 +314,24 @@ def stack_decode(stack_params, cfg, x, caches, cache_len, policy, opts):
             if "pairs" in st:
                 pairs.append(st["pairs"])
             if name is None:
-                new_cache = nc
+                written = new
             else:
-                new_cache[name] = nc
-        return (x, dropped), (new_cache, jnp.stack(pairs) if pairs else ())
+                written[name] = new
+        return (x, dropped), (written, jnp.stack(pairs) if pairs else ())
 
-    (x, dropped), (new_caches, pairs) = jax.lax.scan(
+    (x, dropped), (written, pairs) = jax.lax.scan(
         body, (x, jnp.zeros((), jnp.int32)), (stack_params, caches))
+
+    def merge(cache, new):
+        return {name: (write_kv(leaf, new[name], cache_len)
+                       if name in ("k", "v") else new.get(name, leaf))
+                for name, leaf in cache.items()}
+
+    if per == 1:
+        new_caches = merge(caches, written)
+    else:
+        new_caches = {name: merge(caches[name], written[name])
+                      for name in caches}
     stats = {"moe_pairs_dropped": dropped}
     if not isinstance(pairs, tuple):
         stats["expert_pairs"] = pairs.reshape(-1, pairs.shape[-1])

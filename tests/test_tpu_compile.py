@@ -154,3 +154,36 @@ def test_held_expert_layer_and_mamba2_step_compile(one_chip):
                  jnp.float32, sharding=one_chip)}
     jax.jit(lambda p, x, s: Mb.mamba2_step(p, cfg, x, s)).lower(
         m_p, x, state).compile()
+
+
+def test_serve_step_writes_the_cache_in_place(one_chip):
+    """The chat cell's decode body (granite-3.0-2b's widths, 16 slots, 2560
+    positions; four layers) compiled for the chip with its bfloat16 caches
+    donated: no copy of a whole stacked K or V leaf, and temporaries under
+    a quarter of the caches' bytes (the one-hot write through the layer
+    scan copied both leaves and held a whole cache of temp)."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import model as M
+    from test_decode_cache import whole_leaf_copies
+    cfg = dataclasses.replace(get_config("granite-3-2b"), n_layers=4)
+    B, S = 16, 2560
+
+    def on_chip(tree, dtype=None):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, dtype or s.dtype,
+                                           sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(lambda k: M.init_params(k, cfg),
+                                    jax.random.PRNGKey(0)), jnp.bfloat16)
+    caches = on_chip(M.cache_struct(cfg, B, S))
+    serve = M.make_serve_step(cfg, None)
+    compiled = jax.jit(serve, donate_argnums=(1,)).lower(
+        params, caches,
+        jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)).compile()
+    leaves = jax.tree_util.tree_leaves(caches)
+    cache_bytes = sum(s.size * s.dtype.itemsize for s in leaves)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < cache_bytes / 4, (temp, cache_bytes)
+    assert not whole_leaf_copies(compiled.as_text(), leaves)
