@@ -3,9 +3,8 @@
     PYTHONPATH=src python examples/serve_lm.py
 
 Prefill a batch of prompts, then greedy-decode continuation tokens with
-the static KV cache — the same serve_step the decode_32k / long_500k
-dry-run cells lower on the 512-chip mesh.  Thin wrapper over the
-production serving launcher (repro.launch.serve).
+the static KV cache.  Thin wrapper over the serving launcher
+(repro.launch.serve).
 """
 import os
 import subprocess

@@ -71,8 +71,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from . import local_ops as L
 from .. import trace
 from .context import HptmtContext, shard_map
-from .kernel_backend import radix_impl
-from .kernel_backend import sort_impl as _default_sort_impl
+from .kernel_backend import table_kernel_impl
 from .partition import hash_columns, partition_ids
 from .table import Table, narrow_column as _narrow_column
 from ..kernels import bucketing as _bucketing
@@ -178,7 +177,8 @@ def shuffle_by_pid(ctx: HptmtContext, table: Table, pid: jnp.ndarray,
     names = table.names
     # trash partition `world` for padding rows
     pid = jnp.where(valid, pid, world)
-    hist, ranks = radix_histogram_ranks(pid, world + 1, impl=radix_impl())
+    hist, ranks = radix_histogram_ranks(pid, world + 1,
+                                        impl=table_kernel_impl())
     ok = valid & (ranks < slots_per_dest) & (pid < world)
     flat = jnp.where(ok, pid * slots_per_dest + ranks,
                      world * slots_per_dest)
@@ -368,7 +368,7 @@ def dist_join(ctx: HptmtContext, left: Table, right: Table, *,
     optimization; pick when |right| << |left|).
 
     ``local_impl`` selects the local join backend ('sortmerge' | 'hash',
-    default ``kernel_backend.join_impl()``); ``local_join_sizes`` forwards
+    default ``local_ops.JOIN_IMPL``); ``local_join_sizes`` forwards
     hash-backend static sizing (``num_buckets`` / ``bucket_capacity`` /
     ``probe_capacity``) — both backends return drop-in identical results,
     so the whole distributed join runs hash-local under one shard_map.
@@ -415,7 +415,7 @@ def dist_groupby(ctx: HptmtContext, table: Table, by: Sequence[str],
     """Distributed GroupBy+Aggregate: shuffle on keys + local groupby.
 
     ``local_impl`` selects the local aggregation backend ('sort' | 'hash',
-    default ``kernel_backend.groupby_impl()``); ``groupby_sizes`` forwards
+    default ``local_ops.GROUPBY_IMPL``); ``groupby_sizes`` forwards
     hash-backend static sizing (``num_buckets`` / ``bucket_capacity``).
     Both backends return drop-in identical results, so the whole
     distributed groupby runs hash-local under one shard_map; the hash
@@ -457,7 +457,7 @@ def dist_difference(ctx: HptmtContext, a: Table, b: Table,
     *values*), so per-shard membership is global membership.
 
     ``local_impl`` selects the local semi-join backend ('sortmerge' |
-    'hash', default ``kernel_backend.semi_impl()``); ``semi_sizes``
+    'hash', default ``local_ops.SEMI_IMPL``); ``semi_sizes``
     forwards hash-backend static sizing (``num_buckets`` /
     ``bucket_capacity`` / ``probe_capacity``).  The hash path's slab
     overflow drops join the shuffle drops in the returned counter."""
@@ -518,15 +518,14 @@ def dist_sort(ctx: HptmtContext, table: Table, by: Sequence[str],
     all_to_all, local sort.  Globally sorted = shard order + local order.
 
     ``local_impl`` selects the local sort backend ('xla' | 'radix',
-    default ``kernel_backend.sort_impl()``) for the pre-shuffle and final
+    default ``local_ops.SORT_IMPL``) for the pre-shuffle and final
     local sorts; under 'radix' the gathered splitter candidates are also
     ranked by the radix engine, so the whole distributed sort is
     sort-primitive-free.  Both backends return drop-in bit-identical
     results (same splitters, same routing, same shard-local order)."""
     by = list(by)
-    impl = local_impl or _default_sort_impl()
     world = ctx.world_size
-    ts = L.sort_values(table, by, ascending=ascending, impl=impl)
+    ts = L.sort_values(table, by, ascending=ascending, impl=local_impl)
     cap = ts.capacity
     s = min(n_samples, cap)
     # evenly sample valid rows (clamp handles nvalid < s)
@@ -540,10 +539,10 @@ def dist_sort(ctx: HptmtContext, table: Table, by: Sequence[str],
         sample_keys.append(col)
     gathered = [jax.lax.all_gather(c, ctx.row_axes, tiled=True)
                 for c in sample_keys]                     # (world*s,)
-    if impl == "radix":
+    if local_impl == "radix":
         sperm = radix_permutation(tuple(gathered),
                                   jnp.zeros((world * s,), bool),
-                                  impl=radix_impl())
+                                  impl=table_kernel_impl())
         sorted_keys = tuple(c[sperm] for c in gathered)
     else:
         iota = jnp.arange(world * s, dtype=jnp.int32)
@@ -561,7 +560,8 @@ def dist_sort(ctx: HptmtContext, table: Table, by: Sequence[str],
     pid = _rank_against_splitters(splitters, row_keys)
     slots, out_cap = default_shuffle_sizes(ctx, cap, overcommit)
     sh, dropped = shuffle_by_pid(ctx, ts, pid, slots, out_cap)
-    return L.sort_values(sh, by, ascending=ascending, impl=impl), dropped
+    out = L.sort_values(sh, by, ascending=ascending, impl=local_impl)
+    return out, dropped
 
 
 def _rank_against_splitters(splitters: tuple, row_keys: tuple) -> jnp.ndarray:
@@ -654,7 +654,7 @@ def all_gather_table(ctx: HptmtContext, table: Table) -> Table:
         g = jax.lax.all_gather(v, ctx.row_axes, tiled=True)
         cols[k] = g
     gvalid = jax.lax.all_gather(valid, ctx.row_axes, tiled=True)
-    perm = stable_partition_perm(gvalid, impl=radix_impl())
+    perm = stable_partition_perm(gvalid, impl=table_kernel_impl())
     out = Table(columns={k: v[perm] for k, v in cols.items()},
                 nvalid=jnp.sum(gvalid, dtype=jnp.int32))
     return out

@@ -1,17 +1,12 @@
-"""Select kernel implementations per backend.
+"""Select the table kernels' implementation per backend.
 
-Pallas TPU kernels are the *target*; on CPU (this container) the pure-jnp
-references execute instead, and tests exercise the kernels via
-``interpret=True``.  Environment overrides:
-
-* ``REPRO_KERNEL_IMPL``  — table kernels (radix partition, hash-join
-  probe, hash-groupby accumulate): ``ref | pallas | pallas_interpret``;
-* ``REPRO_JOIN_IMPL``    — local join algorithm: ``sortmerge | hash``;
-* ``REPRO_GROUPBY_IMPL`` — local groupby/dedup algorithm: ``sort | hash``;
-* ``REPRO_SORT_IMPL``    — local sort/OrderBy algorithm: ``xla | radix``;
-* ``REPRO_SEMI_IMPL``    — local semi-join/membership algorithm
-  (isin / intersect / difference): ``sortmerge | hash``;
-* ``REPRO_ATTN_IMPL`` / ``REPRO_MAMBA_IMPL`` — model kernels.
+Pallas TPU kernels are the *target*; on CPU the pure-jnp references
+execute instead, and tests exercise the kernels via ``interpret=True``.
+``REPRO_KERNEL_IMPL`` (``ref | pallas | pallas_interpret``) overrides the
+platform default; it is the only ``REPRO_*`` variable the library reads.
+Which local algorithm each operator runs is fixed in code
+(``local_ops.JOIN_IMPL`` and its siblings), as are the kernels' widths
+(``kernels/tuning.py``).
 """
 import os
 
@@ -28,60 +23,3 @@ def table_kernel_impl() -> str:
     if env:
         return env
     return "pallas" if backend_platform() == "tpu" else "ref"
-
-
-# historical name — the radix kernel was the first table kernel
-radix_impl = table_kernel_impl
-
-
-def join_impl() -> str:
-    """Local join algorithm: 'sortmerge' (default) or 'hash'."""
-    env = os.environ.get("REPRO_JOIN_IMPL")
-    if env:
-        return env
-    return "sortmerge"
-
-
-def groupby_impl() -> str:
-    """Local groupby/aggregate/dedup algorithm: 'sort' (default) or
-    'hash'."""
-    env = os.environ.get("REPRO_GROUPBY_IMPL")
-    if env:
-        return env
-    return "sort"
-
-
-def sort_impl() -> str:
-    """Local sort/OrderBy algorithm: 'xla' (``jax.lax.sort``, default) or
-    'radix' (multi-pass LSD radix rank on ``kernels/radix_sort`` — no
-    ``sort`` primitive anywhere on the path)."""
-    env = os.environ.get("REPRO_SORT_IMPL")
-    if env:
-        return env
-    return "xla"
-
-
-def semi_impl() -> str:
-    """Local semi-join/membership algorithm (isin / _semi_mask /
-    intersect / difference): 'sortmerge' (binary search over sorted keys,
-    default) or 'hash' (bucketed build+probe membership on
-    ``kernels/hash_semi`` — no join materialization, no ``sort``
-    primitive anywhere on the path)."""
-    env = os.environ.get("REPRO_SEMI_IMPL")
-    if env:
-        return env
-    return "sortmerge"
-
-
-def attention_impl() -> str:
-    env = os.environ.get("REPRO_ATTN_IMPL")
-    if env:
-        return env
-    return "pallas" if backend_platform() == "tpu" else "xla"
-
-
-def mamba_impl() -> str:
-    env = os.environ.get("REPRO_MAMBA_IMPL")
-    if env:
-        return env
-    return "pallas" if backend_platform() == "tpu" else "xla"

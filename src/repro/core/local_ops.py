@@ -8,8 +8,8 @@ implemented as pure, jittable, *static-shape* JAX functions over
 
 TPU adaptation notes (see DESIGN.md §2):
 * every op is mask-aware: rows ``>= nvalid`` are padding;
-* local join has two backends selected by ``impl`` (default via
-  ``kernel_backend.join_impl()`` / ``REPRO_JOIN_IMPL``):
+* local join has two backends selected by ``impl`` (default
+  ``JOIN_IMPL``):
 
   - ``"sortmerge"`` — binary search over sorted keys; exact for any key
     distribution, O((L+R) log) sorts per call;
@@ -19,8 +19,7 @@ TPU adaptation notes (see DESIGN.md §2):
     fast path for shuffled (10%-unique-key style) workloads;
 
 * the aggregation family (groupby_aggregate, drop_duplicates) has the
-  same two backends via ``impl`` (default ``kernel_backend.groupby_impl()``
-  / ``REPRO_GROUPBY_IMPL``):
+  same two backends via ``impl`` (default ``GROUPBY_IMPL``):
 
   - ``"sort"`` — lexicographic tuple sort + segment reductions;
   - ``"hash"`` — bucketed hash-accumulate on the ``kernels/hash_groupby``
@@ -30,7 +29,7 @@ TPU adaptation notes (see DESIGN.md §2):
     ``kernels/radix_sort``);
 
 * OrderBy (sort_values) itself has two backends via ``impl`` (default
-  ``kernel_backend.sort_impl()`` / ``REPRO_SORT_IMPL``):
+  ``SORT_IMPL``):
 
   - ``"xla"`` — one stable ``jax.lax.sort`` over (validity, keys, iota);
   - ``"radix"`` — the ``kernels/radix_sort`` multi-pass LSD engine: a
@@ -50,8 +49,7 @@ TPU adaptation notes (see DESIGN.md §2):
   integer-valued data, and agree to rounding otherwise);
 
 * the membership family (isin, semi_mask, intersect, difference) has two
-  backends via ``impl`` (default ``kernel_backend.semi_impl()`` /
-  ``REPRO_SEMI_IMPL``):
+  backends via ``impl`` (default ``SEMI_IMPL``):
 
   - ``"sortmerge"`` — sort the right key set, binary-search each probe;
   - ``"hash"`` — bucketed build+probe membership on ``kernels/hash_semi``:
@@ -82,12 +80,17 @@ from ..kernels.hash_join import default_hash_join_sizes, hash_join_plan
 from ..kernels.hash_semi import default_hash_semi_sizes, hash_semi_plan
 from ..kernels.radix_sort import (radix_permutation, radix_rank,
                                   stable_partition_perm)
-from .kernel_backend import groupby_impl as _default_groupby_impl
-from .kernel_backend import join_impl as _default_join_impl
-from .kernel_backend import semi_impl as _default_semi_impl
-from .kernel_backend import sort_impl as _default_sort_impl
 from .kernel_backend import table_kernel_impl as _default_kernel_impl
 from .table import Table, isnull_values, null_like
+
+# The local algorithm each operator family runs when its caller names none
+# (``impl=None``; the dist_* wrappers and morsel loops forward their
+# ``local_impl`` unchanged).  The other backends run only by an explicit
+# ``impl=``.
+JOIN_IMPL = "sortmerge"
+GROUPBY_IMPL = "sort"
+SORT_IMPL = "xla"
+SEMI_IMPL = "sortmerge"
 
 # --------------------------------------------------------------------------
 # small helpers
@@ -205,7 +208,7 @@ def sort_values(table: Table, by: Sequence[str],
                 kernel_impl: str | None = None) -> Table:
     """Paper's OrderBy: stable multi-key sort; padding rows stay at the end.
 
-    ``impl`` picks the backend (default ``kernel_backend.sort_impl()``):
+    ``impl`` picks the backend (default ``SORT_IMPL``):
     ``"xla"`` (one stable ``jax.lax.sort``) or ``"radix"`` (multi-pass LSD
     radix rank on the ``kernels/radix_sort`` engine — no ``sort``
     primitive in the jaxpr).  Both emit *bit-identical* output — same
@@ -217,7 +220,7 @@ def sort_values(table: Table, by: Sequence[str],
     by = list(by)
     if isinstance(ascending, bool):
         ascending = [ascending] * len(by)
-    impl = impl or _default_sort_impl()
+    impl = impl or SORT_IMPL
     keys = [_sort_key(table.columns[k], a) for k, a in zip(by, ascending)]
     if impl == "xla":
         invalid = (~table.valid_mask).astype(jnp.int32)
@@ -306,7 +309,7 @@ def drop_duplicates(table: Table, subset: Sequence[str] | None = None, *,
                     kernel_impl: str | None = None):
     """Keep the first occurrence of each distinct key (paper: Unique).
 
-    ``impl`` picks the backend (default ``kernel_backend.groupby_impl()``):
+    ``impl`` picks the backend (default ``GROUPBY_IMPL``):
     ``"sort"`` (stable sort + boundary compaction) or ``"hash"`` (key-only
     hash groupby on the ``kernels/hash_groupby`` plan — no sort).  Both
     emit the *canonical* table: one row per distinct key, sorted by the
@@ -317,7 +320,7 @@ def drop_duplicates(table: Table, subset: Sequence[str] | None = None, *,
     dropped and counted (``return_overflow=True`` returns the count).
     """
     subset = list(subset) if subset is not None else list(table.names)
-    impl = impl or _default_groupby_impl()
+    impl = impl or GROUPBY_IMPL
     if impl == "sort":
         out, over = _sort_drop_duplicates(table, subset), jnp.int32(0)
     elif impl == "hash":
@@ -381,7 +384,7 @@ def groupby_aggregate(table: Table, by: Sequence[str],
     {sum,count,mean,min,max}.  Output columns are named ``{col}_{agg}``;
     one row per distinct key, capacity preserved.
 
-    ``impl`` picks the backend (default ``kernel_backend.groupby_impl()``):
+    ``impl`` picks the backend (default ``GROUPBY_IMPL``):
     ``"sort"`` (lexicographic sort + segment reductions) or ``"hash"``
     (bucketed hash-accumulate on the ``kernels/hash_groupby`` kernel — no
     sort anywhere on the path).  Both emit the *canonical* table: one row
@@ -400,7 +403,7 @@ def groupby_aggregate(table: Table, by: Sequence[str],
         for op in ops:
             if op not in _AGGS:
                 raise ValueError(f"unknown aggregation {op!r}")
-    impl = impl or _default_groupby_impl()
+    impl = impl or GROUPBY_IMPL
     if impl == "sort":
         out, over = _sort_groupby(table, by, aggs), jnp.int32(0)
     elif impl == "hash":
@@ -715,7 +718,7 @@ def join(left: Table, right: Table, *,
          kernel_impl: str | None = None):
     """Paper's Join: inner/left join with static output capacity.
 
-    ``impl`` picks the backend (default ``kernel_backend.join_impl()``):
+    ``impl`` picks the backend (default ``JOIN_IMPL``):
     ``"sortmerge"`` or ``"hash"``.  Both emit *identical* output — same
     rows, same order: left-row-major, and within a left row its matches in
     the right table's original row order — so they are drop-in
@@ -731,7 +734,7 @@ def join(left: Table, right: Table, *,
     """
     if how not in ("inner", "left"):
         raise ValueError("how must be 'inner' or 'left'")
-    impl = impl or _default_join_impl()
+    impl = impl or JOIN_IMPL
     left_on = list(left_on)
     right_on = list(right_on) if right_on is not None else left_on
     out_cap = out_capacity or left.capacity
@@ -1032,9 +1035,9 @@ def semi_mask(left: Table, right: Table, left_on: Sequence[str],
     """Semi-join membership mask: per left row, does its key appear among
     the right table's valid keys?
 
-    ``impl`` picks the backend (default ``kernel_backend.semi_impl()`` /
-    ``REPRO_SEMI_IMPL``): ``"sortmerge"`` (binary search over the sorted
-    right key set) or ``"hash"`` (bucketed build+probe membership on the
+    ``impl`` picks the backend (default ``SEMI_IMPL``): ``"sortmerge"``
+    (binary search over the sorted right key set) or ``"hash"`` (bucketed
+    build+probe membership on the
     ``kernels/hash_semi`` plan — no join materialization, no ``sort``
     primitive anywhere on the path).  Both emit the *bit-identical* mask
     — key pairs are compared in their promoted common dtype either way —
@@ -1048,7 +1051,7 @@ def semi_mask(left: Table, right: Table, left_on: Sequence[str],
     (``return_overflow=True`` returns the count)."""
     left_on = list(left_on)
     right_on = list(right_on) if right_on is not None else left_on
-    impl = impl or _default_semi_impl()
+    impl = impl or SEMI_IMPL
     qkeys, vkeys = _promoted_semi_keys(left, right, left_on, right_on)
     if impl == "sortmerge":
         mask, over = _sortmerge_semi(qkeys, left.valid_mask, vkeys,
@@ -1081,7 +1084,7 @@ def isin(table: Table, col: str, values: Table, values_col: str, *,
     (UNOMT Fig. 11).  Keys are compared in the promoted common dtype of
     the two columns, so e.g. a float32 probe against an int32 values
     table cannot collide distinct keys.  See :func:`semi_mask` for the
-    backend (``impl`` / ``REPRO_SEMI_IMPL``) and overflow contracts."""
+    backend (``impl``) and overflow contracts."""
     return semi_mask(table, values, [col], [values_col], impl=impl,
                      return_overflow=return_overflow,
                      num_buckets=num_buckets,
@@ -1100,7 +1103,7 @@ def intersect(a: Table, b: Table, on: Sequence[str] | None = None, *,
 
     ``impl`` selects the semi-join backend (see :func:`semi_mask`);
     ``dedup_impl`` the dedup backend (see :func:`drop_duplicates`,
-    default ``kernel_backend.groupby_impl()``).  Output is the canonical
+    default ``GROUPBY_IMPL``).  Output is the canonical
     table (one row per distinct key, sorted by the ``on`` columns) —
     bit-identical across all backend combinations.
     ``return_overflow=True`` returns the summed semi + dedup overflow."""
@@ -1153,7 +1156,7 @@ def union(a: Table, b: Table, on: Sequence[str] | None = None, *,
     rows win ties against ``b``'s.
 
     ``impl`` selects the dedup backend ('sort' | 'hash', see
-    :func:`drop_duplicates` / ``REPRO_GROUPBY_IMPL``) with its static
+    :func:`drop_duplicates`) with its static
     sizing; rows overflowing a hash bucket slab are dropped and counted
     (``return_overflow=True`` returns the count) — never silently lost."""
     on = list(on) if on is not None else list(a.names)

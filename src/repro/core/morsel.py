@@ -1,7 +1,6 @@
 """Out-of-core, morsel-driven execution over the distributed operators.
 
-Every bench in ``results/bench.json`` used to top out near 200k rows
-because a table had to fit device memory in one piece.  This module
+Without chunking, a table has to fit device memory in one piece.  This module
 removes that ceiling the way the paper's predecessor systems do (Cylon's
 streaming shuffle, linear-dag's blockwise operators over HDF5): the
 *operator contract* — communication ∘ local operator with counted

@@ -102,7 +102,7 @@ def unomt_local_pipeline(resp: Table, desc: Table, fp: Table, rna: Table,
     """Single-partition version of Figures 8–11 (jittable).
 
     ``semi_impl`` selects the Fig.-11 membership backend ('sortmerge' |
-    'hash', default ``kernel_backend.semi_impl()``)."""
+    'hash', default ``local_ops.SEMI_IMPL``)."""
     t = _clean_response(resp)
     drug = L.join(desc, fp, left_on=["drug_id"],
                   out_capacity=desc.capacity)              # Fig. 9
@@ -128,7 +128,7 @@ def unomt_dist_pipeline(ctx: HptmtContext, resp: Table, desc: Table,
     (features table, total dropped rows) — run under DistributedPipeline.
 
     ``semi_impl`` selects the membership backend for the Fig.-11 filter
-    ('sortmerge' | 'hash', default ``kernel_backend.semi_impl()``).
+    ('sortmerge' | 'hash', default ``local_ops.SEMI_IMPL``).
     """
     t = _clean_response(resp, ctx)
     drug, d1 = D.dist_join(ctx, desc, fp, left_on=["drug_id"],

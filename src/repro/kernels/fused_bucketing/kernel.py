@@ -19,8 +19,7 @@ embarrassingly parallel (``dimension_semantics=("parallel",)``).
 
 VMEM budget: the one-hot is ``(P+1) x 128`` per chunk whatever the tile —
 P=512 means 257 KiB, far under the 16 MiB scoped VMEM of TPU v5e.
-``tile`` is resolved through ``kernels.autotune`` (``REPRO_TILE``
-override).
+``tile`` defaults to ``kernels/tuning.py``'s ``TILE``.
 """
 import functools
 

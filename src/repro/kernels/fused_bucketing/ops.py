@@ -4,15 +4,15 @@
 given key bit-planes and a validity mask it returns, in one fused pass,
 each row's bucket id, the per-bucket histogram (trash bucket included)
 and each row's stable within-bucket rank — everything the slab scatter
-needs.  The tile shape is resolved through ``kernels.autotune``
-(``REPRO_TILE`` override) at trace time.
+needs.  The tile shape is ``kernels/tuning.py``'s ``TILE`` unless the
+caller passes one.
 """
 import functools
 
 import jax
 import jax.numpy as jnp
 
-from .. import autotune
+from ..tuning import TILE
 from .kernel import fused_bucket_ranks_tiles
 from .ref import fused_bucket_ranks_ref
 
@@ -45,10 +45,8 @@ def _fused_bucket_ranks(bits: tuple, valid: jnp.ndarray, num_buckets: int,
 
 
 def fused_bucket_ranks(bits: tuple, valid: jnp.ndarray, num_buckets: int,
-                       *, impl: str = "ref", tile: int | None = None):
+                       *, impl: str = "ref", tile: int = TILE):
     """(bid (n,), hist (P+1,), ranks (n,)) — see ``ref.py`` for the
     contract.  impl: 'ref' (pure jnp), 'pallas' (TPU), 'pallas_interpret'
-    (CPU check); ``tile=None`` resolves via the autotuner."""
-    if tile is None:
-        tile = autotune.tuned("tile", impl, valid.shape[0])
+    (CPU check)."""
     return _fused_bucket_ranks(tuple(bits), valid, num_buckets, impl, tile)

@@ -98,7 +98,7 @@ def default_hash_groupby_sizes(capacity: int,
 
     Small tables (capacity <= ``bucketing.EXACT_SLAB_CAP``) get
     full-capacity slabs: every key distribution — including all-equal
-    keys — aggregates with zero overflow, so the env-default hash backend
+    keys — aggregates with zero overflow, so the auto-sized hash backend
     is exact wherever the sort backend is.  Larger tables get ~16 rows
     per bucket on average (``bucketing.default_bucket_count``) with 4x
     headroom — an assumption of ~uniform key spread; with *concrete*

@@ -128,7 +128,7 @@ def default_hash_join_sizes(left_capacity: int, right_capacity: int,
 
     Small tables (both capacities <= ``bucketing.EXACT_SLAB_CAP``) get
     full-capacity slabs: every key distribution — including all-equal
-    keys — fits with zero overflow, so the env-default hash backend is
+    keys — fits with zero overflow, so the auto-sized hash backend is
     exact wherever the sort-merge backend is.  Larger tables get ~16
     build rows per bucket on average with 4x headroom per slab — an
     assumption of ~uniform key spread; with *concrete* (non-traced) keys

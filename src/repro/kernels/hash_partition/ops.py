@@ -11,16 +11,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .. import autotune
+from ..tuning import TILE
 from .kernel import radix_histogram_ranks_tiles
 from .ref import radix_histogram_ranks_ref
-
-_DEFAULT_TILE = 1024
 
 
 @functools.partial(jax.jit, static_argnames=("num_partitions", "impl", "tile"))
 def _radix_histogram_ranks(pid: jnp.ndarray, num_partitions: int,
-                           impl: str = "ref", tile: int = _DEFAULT_TILE):
+                           impl: str = "ref", tile: int = TILE):
     n = pid.shape[0]
     if impl == "ref" or n < tile:
         return radix_histogram_ranks_ref(pid, num_partitions)
@@ -47,20 +45,17 @@ def _radix_histogram_ranks(pid: jnp.ndarray, num_partitions: int,
 
 
 def radix_histogram_ranks(pid: jnp.ndarray, num_partitions: int,
-                          impl: str = "ref", tile: int | None = None):
+                          impl: str = "ref", tile: int = TILE):
     """hist (P,), ranks (n,) — stable within-partition ranks.
 
     impl: 'ref' (pure jnp), 'pallas' (TPU), 'pallas_interpret' (CPU check).
-    ``tile=None`` resolves through the autotuner (``REPRO_TILE`` override).
     """
-    if tile is None:
-        tile = autotune.tuned("tile", impl, pid.shape[0])
     return _radix_histogram_ranks(pid, num_partitions, impl=impl, tile=tile)
 
 
 @functools.partial(jax.jit, static_argnames=("num_partitions", "impl", "tile"))
 def _partition_plan(pid: jnp.ndarray, num_partitions: int,
-                    impl: str = "ref", tile: int = _DEFAULT_TILE):
+                    impl: str = "ref", tile: int = TILE):
     hist, ranks = _radix_histogram_ranks(pid, num_partitions, impl=impl,
                                          tile=tile)
     offsets = jnp.cumsum(hist) - hist
@@ -68,13 +63,10 @@ def _partition_plan(pid: jnp.ndarray, num_partitions: int,
 
 
 def partition_plan(pid: jnp.ndarray, num_partitions: int,
-                   impl: str = "ref", tile: int | None = None):
+                   impl: str = "ref", tile: int = TILE):
     """(hist, dest): dest[i] = exclusive_offset[pid[i]] + rank[i].
 
     Scattering row i to slot ``dest[i]`` groups rows by partition, stable
     within each partition (exactly Cylon's hash-partition layout).
-    ``tile=None`` resolves through the autotuner (``REPRO_TILE`` override).
     """
-    if tile is None:
-        tile = autotune.tuned("tile", impl, pid.shape[0])
     return _partition_plan(pid, num_partitions, impl=impl, tile=tile)

@@ -1,4 +1,3 @@
 """Multi-pass LSD radix sort/rank engine (kernel/ops/ref, see README)."""
-from .ops import (DEFAULT_RADIX_BITS, grouped_ranks,  # noqa: F401
-                  radix_permutation, radix_rank, sortable_word,
-                  stable_partition_perm)
+from .ops import (grouped_ranks, radix_permutation,  # noqa: F401
+                  radix_rank, sortable_word, stable_partition_perm)

@@ -33,16 +33,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .. import autotune
+from ..tuning import RADIX_BITS, TILE
 from .kernel import digit_histogram_ranks_tiles
 from .ref import digit_histogram_ranks_ref, extract_digits
-
-# historical defaults — the public ops now resolve ``radix_bits``/``tile``
-# through ``kernels.autotune`` (per-backend cache, ``REPRO_RADIX_BITS`` /
-# ``REPRO_TILE`` overrides, optional first-use measurement sweep); these
-# constants remain the autotuner's fallback values.
-_DEFAULT_TILE = 1024
-DEFAULT_RADIX_BITS = 8
 
 _SIGN = jnp.int32(-2 ** 31)
 
@@ -104,8 +97,8 @@ def _scatter_pass(perm: jnp.ndarray, words: jnp.ndarray, shift: int,
                    static_argnames=("impl", "radix_bits", "tile"))
 def _radix_permutation(cols: tuple, invalid: jnp.ndarray, *,
                        impl: str = "ref",
-                       radix_bits: int = DEFAULT_RADIX_BITS,
-                       tile: int = _DEFAULT_TILE) -> jnp.ndarray:
+                       radix_bits: int = RADIX_BITS,
+                       tile: int = TILE) -> jnp.ndarray:
     n = invalid.shape[0]
     perm = jnp.arange(n, dtype=jnp.int32)
     for col in reversed(cols):                 # least-significant key first
@@ -119,18 +112,15 @@ def _radix_permutation(cols: tuple, invalid: jnp.ndarray, *,
 
 
 def radix_permutation(cols: tuple, invalid: jnp.ndarray, *,
-                      impl: str = "ref", radix_bits: int | None = None,
-                      tile: int | None = None) -> jnp.ndarray:
+                      impl: str = "ref", radix_bits: int = RADIX_BITS,
+                      tile: int = TILE) -> jnp.ndarray:
     """Stable gather index sorting by ``cols`` lexicographically ascending,
     rows with ``invalid`` set last — bit-identical to the permutation of a
     stable ``lax.sort((invalid, *cols, iota))``.
 
     impl: 'ref' (pure jnp), 'pallas' (TPU), 'pallas_interpret' (CPU check).
-    ``radix_bits``/``tile`` default to the autotuner's choice for this
-    backend and size class (``REPRO_RADIX_BITS``/``REPRO_TILE`` override).
+    ``radix_bits``/``tile`` default to ``kernels/tuning.py``'s widths.
     """
-    radix_bits, tile = autotune.radix_params(impl, invalid.shape[0],
-                                             radix_bits, tile)
     return _radix_permutation(tuple(cols), invalid, impl=impl,
                               radix_bits=radix_bits, tile=tile)
 
@@ -147,13 +137,11 @@ def _radix_rank(cols: tuple, invalid: jnp.ndarray, *, impl: str,
 
 
 def radix_rank(cols: tuple, invalid: jnp.ndarray, *, impl: str = "ref",
-               radix_bits: int | None = None,
-               tile: int | None = None) -> jnp.ndarray:
+               radix_bits: int = RADIX_BITS,
+               tile: int = TILE) -> jnp.ndarray:
     """Each row's stable output position under the same order (the inverse
     of :func:`radix_permutation`): valid rows with globally distinct keys
     get exactly their canonical (key-sorted) slot in ``[0, n_valid)``."""
-    radix_bits, tile = autotune.radix_params(impl, invalid.shape[0],
-                                             radix_bits, tile)
     return _radix_rank(tuple(cols), invalid, impl=impl,
                        radix_bits=radix_bits, tile=tile)
 
@@ -168,13 +156,11 @@ def _stable_partition_perm(keep: jnp.ndarray, *, impl: str,
 
 
 def stable_partition_perm(keep: jnp.ndarray, *, impl: str = "ref",
-                          tile: int | None = None) -> jnp.ndarray:
+                          tile: int = TILE) -> jnp.ndarray:
     """1-bit fast path: gather index moving ``keep`` rows to the front,
     stable — bit-identical to ``argsort(~keep, stable=True)`` in a single
     counting pass (the compaction hot loop of ``compact()``/``select()``
     and the shuffle's receive side)."""
-    if tile is None:
-        tile = autotune.tuned("tile", impl, keep.shape[0])
     return _stable_partition_perm(keep, impl=impl, tile=tile)
 
 
@@ -206,12 +192,9 @@ def _grouped_ranks(pid: jnp.ndarray, num_partitions: int, *,
 
 
 def grouped_ranks(pid: jnp.ndarray, num_partitions: int, *,
-                  impl: str = "ref", radix_bits: int | None = None,
-                  tile: int | None = None):
+                  impl: str = "ref", radix_bits: int = RADIX_BITS,
+                  tile: int = TILE):
     """(hist (P,), stable within-partition ranks (n,)) for any ``P`` —
-    see :func:`_grouped_ranks`; ``radix_bits``/``tile`` resolve through
-    the autotuner when omitted."""
-    radix_bits, tile = autotune.radix_params(impl, pid.shape[0],
-                                             radix_bits, tile)
+    see :func:`_grouped_ranks`."""
     return _grouped_ranks(pid, num_partitions, impl=impl,
                           radix_bits=radix_bits, tile=tile)

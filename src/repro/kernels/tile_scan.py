@@ -15,8 +15,8 @@ relayout.
 import jax
 import jax.numpy as jnp
 
-# rows per scan step: one MXU pass on v5e (128x128), and the tile sizes the
-# autotuner picks (512/1024/2048) are all multiples of it
+# rows per scan step: one MXU pass on v5e (128x128); ``tuning.TILE`` is a
+# multiple of it
 CHUNK = 128
 
 
@@ -42,7 +42,7 @@ def tile_hist_ranks(ids_of, rank_ref, tile: int, width: int) -> jnp.ndarray:
     ranks — the number of earlier rows of the tile with the same id — are
     stored to ``rank_ref[0, :, lo:hi]``.  Returns the ``(1, width)``
     histogram.  The one-hot working set is ``width x CHUNK``, whatever the
-    tile, so VMEM stays flat as the autotuner widens the tile.
+    tile, so VMEM stays flat whatever the tile.
     """
     c = CHUNK if tile % CHUNK == 0 else tile
     upper = strict_upper(c)

@@ -5,8 +5,8 @@ Three implementations share one contract (``q (B,Hq,Sq,D)``, ``k/v
 
 * ``full``    — one einsum; used when the score matrix is small;
 * ``chunked`` — online-softmax over (q-chunk, kv-chunk) tiles expressed as
-  ``lax.scan`` (the XLA-native flash attention used by the dry-run and the
-  long-context shapes; per-step score tiles are ``jax.checkpoint``-ed so
+  ``lax.scan`` (the XLA-native flash attention used for long-context
+  shapes; per-step score tiles are ``jax.checkpoint``-ed so
   the backward never materializes the full score matrix);
 * ``pallas``  — the fused ``kernels/flash_attention`` TPU kernel.
 

@@ -343,7 +343,7 @@ def write_cache_slot(caches, one, slot):
 
 
 # --------------------------------------------------------------------------
-# cache shape construction (for dry-run input_specs and serving)
+# cache shape construction (for sharding specs and serving)
 # --------------------------------------------------------------------------
 
 

@@ -1,10 +1,9 @@
 """Shared fixtures/oracles for the repro test suite.
 
 NOTE: no XLA_FLAGS tweaking here — in-process tests run on the single real
-CPU device (per the assignment: only launch/dryrun.py builds the 512-device
-placeholder mesh).  Multi-device distributed behaviour is exercised by
-``tests/dist/dist_checks.py`` in a subprocess with
-``--xla_force_host_platform_device_count=8``.
+CPU device.  Multi-device distributed behaviour is exercised by the
+workers under ``tests/dist/`` in subprocesses with
+``--xla_force_host_platform_device_count=N``.
 """
 from __future__ import annotations
 

@@ -2,7 +2,10 @@
 empty-shard inputs through the distributed operators at a given world
 size.
 
-Usage: XLA_FLAGS=...device_count=W python empty_conformance.py W
+Usage: XLA_FLAGS=...device_count=W python empty_conformance.py W [B]
+
+``B`` names the local algorithms: ``default`` (each family's default) or
+``hash`` (hash join, groupby and semi-join, radix sort).
 
 Three degenerate shapes per operator:
 
@@ -24,9 +27,15 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 from oracles import (as_sets, np_groupby_aggregate, np_isin, np_join,  # noqa: E402
                      np_sort_values)
 
+BACKENDS = {"default": {"join": None, "groupby": None, "sort": None,
+                        "semi": None},
+            "hash": {"join": "hash", "groupby": "hash", "sort": "radix",
+                     "semi": "hash"}}
+
 
 def main():
     world = int(sys.argv[1])
+    impl = BACKENDS[sys.argv[2] if len(sys.argv) > 2 else "default"]
     import jax
     from jax.sharding import Mesh
     from repro.core import dist_ops as D
@@ -57,7 +66,8 @@ def main():
             for lname, l, r in ((f"{name}-left", probe, full),
                                 (f"{name}-right", full, probe)):
                 got = run(lambda c, a, b, how=how: D.dist_join(
-                    c, a, b, left_on=["k"], how=how, out_capacity=256),
+                    c, a, b, left_on=["k"], how=how, out_capacity=256,
+                    local_impl=impl["join"]),
                     dist(l), dist(r))
                 lv = {"k": l["k"], "lv": l["v"]}
                 rv = {"k": r["k"], "rv": r["v"]}
@@ -66,14 +76,17 @@ def main():
                 assert as_sets(got) == as_sets(want), (lname, how)
         # groupby
         got = run(lambda c, t: D.dist_groupby(
-            c, t, ["k"], {"v": ["sum", "mean", "count"]}), dist(probe))
+            c, t, ["k"], {"v": ["sum", "mean", "count"]},
+            local_impl=impl["groupby"]), dist(probe))
         want = np_groupby_aggregate(probe, ["k"],
                                     {"v": ["sum", "mean", "count"]})
         assert as_sets(got) == as_sets(
             {k: np.asarray(v) for k, v in want.items()}), name
         # sort (shard order + local order == global order, even with
         # empty shards in between after range partition)
-        got = run(lambda c, t: D.dist_sort(c, t, ["k"]), dist(probe))
+        got = run(lambda c, t: D.dist_sort(c, t, ["k"],
+                                           local_impl=impl["sort"]),
+                  dist(probe))
         want = np_sort_values(probe, ["k"])
         for k in want:
             np.testing.assert_array_equal(got[k], want[k],
@@ -81,8 +94,9 @@ def main():
         # isin: empty/sparse table x full values, and full x empty/sparse
         for lname, t, v in ((f"{name}-tbl", probe, full),
                             (f"{name}-vals", full, probe)):
-            got = run(lambda c, a, b: D.dist_isin(c, a, "k", b, "k"),
-                      dist(t), dist(v))
+            got = run(lambda c, a, b: D.dist_isin(
+                c, a, "k", b, "k", local_impl=impl["semi"]),
+                dist(t), dist(v))
             mask = np.asarray(np_isin(t, "k", v, "k"), dtype=bool)
             want = {k: np.asarray(col)[mask] for k, col in t.items()}
             assert as_sets(got) == as_sets(want), lname

@@ -1,7 +1,11 @@
 """Subprocess worker for tests/test_morsel.py: chunked (morsel-driven)
 execution conformance at a given world size.
 
-Usage: XLA_FLAGS=...device_count=W python morsel_conformance.py W
+Usage: XLA_FLAGS=...device_count=W python morsel_conformance.py W [B]
+
+``B`` names the local algorithms: ``default`` (each family's default) or
+``hash`` (hash join and groupby, radix sort), passed to both the chunked
+and the monolithic operators.
 
 Checks the out-of-core chunk loops against the monolithic distributed
 operators on data that *fits*, where results must agree exactly:
@@ -28,6 +32,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 from oracles import as_sets, np_groupby_aggregate, np_join  # noqa: E402
 
+BACKENDS = {"default": {"join": None, "groupby": None, "sort": None},
+            "hash": {"join": "hash", "groupby": "hash", "sort": "radix"}}
+
 
 def canon(d: dict) -> dict:
     order = np.lexsort(tuple(np.nan_to_num(d[k], nan=-1e9)
@@ -45,6 +52,7 @@ def assert_same(a: dict, b: dict, msg=""):
 
 def main():
     world = int(sys.argv[1])
+    impl = BACKENDS[sys.argv[2] if len(sys.argv) > 2 else "default"]
     import jax
     from jax.sharding import Mesh
     from repro.core import dist_ops as D
@@ -66,7 +74,8 @@ def main():
     gl = D.distribute_table(ctx, left)
     gr = D.distribute_table(ctx, right)
     pipe = D.DistributedPipeline(ctx, lambda c, a, b: D.dist_join(
-        c, a, b, left_on=["k"], out_capacity=out_cap))
+        c, a, b, left_on=["k"], out_capacity=out_cap,
+        local_impl=impl["join"]))
     mono, md = pipe(gl, gr)
     assert int(np.max(np.asarray(md))) == 0
     mono = canon(D.collect_table(ctx, mono))
@@ -74,7 +83,7 @@ def main():
         out, dropped = M.chunked_dist_join(
             ctx, M.ChunkedTable(left, chunk),
             M.ChunkedTable(right, rchunk), left_on=["k"], build=build,
-            out_capacity_per_shard=out_cap)
+            out_capacity_per_shard=out_cap, local_impl=impl["join"])
         assert dropped == 0, build
         assert_same(canon(out), mono, f"join/{build}")
         print(f"join/{build}: ok ({len(out['k'])} rows)", flush=True)
@@ -89,7 +98,8 @@ def main():
     rsub32 = {k: v[::2] for k, v in rk32.items()}
     outl, dl = M.chunked_dist_join(
         ctx, M.ChunkedTable(left, chunk), rsub, left_on=["k"],
-        how="left", out_capacity_per_shard=out_cap)
+        how="left", out_capacity_per_shard=out_cap,
+        local_impl=impl["join"])
     assert dl == 0
     assert np.isnan(outl["rv"]).any()   # unmatched rows really occur
     assert as_sets(canon(outl)) == as_sets(np_join(lk32, rsub32, "left"))
@@ -102,13 +112,15 @@ def main():
     gsizes = {"num_buckets": 8, "bucket_capacity": rows}
     aggs = {"lv": ["sum", "mean", "count", "min", "max"]}
     gp = D.DistributedPipeline(ctx, lambda c, t: D.dist_groupby(
-        c, t, ["k"], aggs, groupby_sizes=gsizes))
+        c, t, ["k"], aggs, local_impl=impl["groupby"],
+        groupby_sizes=gsizes))
     monog, gd = gp(gl)
     assert int(np.max(np.asarray(gd))) == 0
     monog = D.collect_table(ctx, monog)
     cg, cgd = M.chunked_dist_groupby(ctx, M.ChunkedTable(left, chunk),
                                      ["k"], aggs,
                                      group_capacity_per_shard=nkeys,
+                                     local_impl=impl["groupby"],
                                      groupby_sizes=gsizes)
     assert cgd == 0
     assert_same(cg, monog, "groupby")
@@ -124,26 +136,32 @@ def main():
     # ---- sort: chunked runs + k-way merge vs monolithic, bit-identical
     for ascending in (True, False):
         sp = D.DistributedPipeline(ctx, lambda c, t, a=ascending:
-                                   D.dist_sort(c, t, ["k"], ascending=a))
+                                   D.dist_sort(c, t, ["k"], ascending=a,
+                                               local_impl=impl["sort"]))
         monos, sd = sp(gl)
         assert int(np.max(np.asarray(sd))) == 0
         monos = D.collect_table(ctx, monos)
         cs, csd = M.chunked_dist_sort(ctx, M.ChunkedTable(left, chunk),
-                                      ["k"], ascending=ascending)
+                                      ["k"], ascending=ascending,
+                                      local_impl=impl["sort"])
         assert csd == 0
         assert_same(cs, monos, f"sort asc={ascending}")
         print(f"sort asc={ascending}: ok (ties bit-identical)", flush=True)
 
     # ---- zero-row sources: one empty terminal morsel per op
     empty = {"k": np.zeros(0, np.int64), "lv": np.zeros(0, np.float64)}
-    eo, ed = M.chunked_dist_join(ctx, empty, right, left_on=["k"])
+    eo, ed = M.chunked_dist_join(ctx, empty, right, left_on=["k"],
+                                 local_impl=impl["join"])
     assert ed == 0 and len(eo["k"]) == 0
     eo, ed = M.chunked_dist_join(ctx, empty, M.ChunkedTable(right, 64),
-                                 left_on=["k"], build="restream")
+                                 left_on=["k"], build="restream",
+                                 local_impl=impl["join"])
     assert ed == 0 and len(eo["k"]) == 0
-    eg, ed = M.chunked_dist_groupby(ctx, empty, ["k"], {"lv": "mean"})
+    eg, ed = M.chunked_dist_groupby(ctx, empty, ["k"], {"lv": "mean"},
+                                    local_impl=impl["groupby"])
     assert ed == 0 and len(eg["k"]) == 0
-    es, ed = M.chunked_dist_sort(ctx, empty, ["k"])
+    es, ed = M.chunked_dist_sort(ctx, empty, ["k"],
+                                 local_impl=impl["sort"])
     assert ed == 0 and len(es["k"]) == 0
     print("empty sources: ok", flush=True)
 

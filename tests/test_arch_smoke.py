@@ -2,7 +2,7 @@
 
 For each of the 10 assigned architectures: instantiate the REDUCED config
 of the same family and run one forward/train step on CPU, asserting output
-shapes and no NaNs.  (The FULL configs are exercised only via the dry-run.)
+shapes and no NaNs.
 """
 import jax
 import jax.numpy as jnp

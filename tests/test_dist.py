@@ -1,8 +1,7 @@
 """Driver for the multi-device distributed checks.
 
 Runs tests/dist/dist_checks.py in a subprocess with 8 forced host devices
-so the main pytest process keeps the single real CPU device (the
-assignment's rule: only the dry-run builds placeholder meshes).
+so the main pytest process keeps the single real CPU device.
 """
 import os
 import subprocess

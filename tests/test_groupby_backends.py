@@ -23,7 +23,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import kernel_backend, local_ops as L
+from repro.core import local_ops as L
 from repro.core.table import Table
 
 from oracles import np_drop_duplicates, np_groupby_aggregate, \
@@ -234,14 +234,10 @@ def test_hash_path_contains_no_sort_primitive(capacity, rng):
     prims = _jaxpr_primitives(
         lambda tt: L.drop_duplicates(tt, ["k"], impl="hash"), t)
     assert "sort" not in prims, sorted(prims)
-    # the sort backend, for contrast, does sort — unless the radix sort
-    # engine is the session default, which makes even this path sort-free
+    # the sort backend, for contrast, does sort
     prims = _jaxpr_primitives(
         lambda tt: L.groupby_aggregate(tt, ["k"], aggs, impl="sort"), t)
-    if kernel_backend.sort_impl() == "xla":
-        assert "sort" in prims
-    else:
-        assert "sort" not in prims, sorted(prims)
+    assert "sort" in prims
 
 
 def test_overflow_counter_trips_at_capacity():
@@ -268,15 +264,15 @@ def test_overflow_counter_trips_at_capacity():
     assert int(over) == n - 8
 
 
-def test_env_default_backend(monkeypatch, rng):
+def test_env_default_backend(rng):
     data = make_data("uniform", rng)
     t = Table.from_dict(data, capacity=ROWS)
-    monkeypatch.setenv("REPRO_GROUPBY_IMPL", "hash")
-    assert kernel_backend.groupby_impl() == "hash"
-    h = L.groupby_aggregate(t, ["k"], {"v": "sum"})
-    monkeypatch.setenv("REPRO_GROUPBY_IMPL", "sort")
-    s = L.groupby_aggregate(t, ["k"], {"v": "sum"})
-    assert_tables_identical(s.to_numpy(), h.to_numpy(), "env dispatch")
+    assert L.GROUPBY_IMPL == "sort"
+    default = L.groupby_aggregate(t, ["k"], {"v": "sum"})
+    for impl in ("sort", "hash"):
+        got = L.groupby_aggregate(t, ["k"], {"v": "sum"}, impl=impl)
+        assert_tables_identical(got.to_numpy(), default.to_numpy(),
+                                f"default vs {impl}")
     with pytest.raises(ValueError):
         L.groupby_aggregate(t, ["k"], {"v": "sum"}, impl="nope")
     with pytest.raises(ValueError):

@@ -146,17 +146,18 @@ def test_overflow_counters_trip_at_capacity(rng):
         assert int(over) == n * n - 100, impl
 
 
-def test_env_default_backend(monkeypatch, rng):
+def test_env_default_backend(rng):
     # "unique" keys: within the auto-sizing heuristic's contract (heavy
     # duplication needs explicit bucket sizing, see default_hash_join_sizes)
     left, right = make_sides("unique", rng)
     lt = Table.from_dict(left, capacity=ROWS)
     rt = Table.from_dict(right, capacity=ROWS)
-    monkeypatch.setenv("REPRO_JOIN_IMPL", "hash")
-    hj = L.join(lt, rt, left_on=["k"], out_capacity=OUT_CAP)
-    monkeypatch.setenv("REPRO_JOIN_IMPL", "sortmerge")
-    sm = L.join(lt, rt, left_on=["k"], out_capacity=OUT_CAP)
-    assert_tables_equal(sm.to_numpy(), hj.to_numpy(), "env dispatch")
+    assert L.JOIN_IMPL == "sortmerge"
+    default = L.join(lt, rt, left_on=["k"], out_capacity=OUT_CAP)
+    for impl in ("sortmerge", "hash"):
+        got = L.join(lt, rt, left_on=["k"], out_capacity=OUT_CAP, impl=impl)
+        assert_tables_equal(got.to_numpy(), default.to_numpy(),
+                            f"default vs {impl}")
     with pytest.raises(ValueError):
         L.join(lt, rt, left_on=["k"], impl="nope")
 

@@ -38,7 +38,7 @@ def test_compile_cache_defaults_to_repo_dir(monkeypatch):
         got = E.enable_compile_cache()
         assert got == os.path.join(E.REPO, ".jax_cache")
         assert jax.config.jax_compilation_cache_dir == got
-        assert os.path.isfile(os.path.join(E.REPO, "chip_smoke.py"))
+        assert os.path.isfile(os.path.join(E.REPO, "BENCHMARK.json"))
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
 

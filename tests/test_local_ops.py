@@ -56,43 +56,43 @@ def test_concat_schema_mismatch():
 # --------------------------------------------------------------------------
 
 
-def test_sort_single_key(rng):
+def test_sort_single_key(rng, impl=None):
     vals = rng.integers(0, 50, 40)
     t = mk({"k": vals, "i": np.arange(40)}, capacity=64)
-    out = L.sort_values(t, ["k"]).to_numpy()
+    out = L.sort_values(t, ["k"], impl=impl).to_numpy()
     np.testing.assert_array_equal(out["k"], np.sort(vals))
 
 
-def test_sort_is_stable(rng):
+def test_sort_is_stable(rng, impl=None):
     keys = rng.integers(0, 4, 32)
     t = mk({"k": keys, "i": np.arange(32)})
-    out = L.sort_values(t, ["k"]).to_numpy()
+    out = L.sort_values(t, ["k"], impl=impl).to_numpy()
     for k in range(4):
         sub = out["i"][out["k"] == k]
         assert (np.diff(sub) > 0).all(), "within-key order must be stable"
 
 
-def test_sort_multi_key_matches_lexsort(rng):
+def test_sort_multi_key_matches_lexsort(rng, impl=None):
     a = rng.integers(0, 5, 30)
     b = rng.integers(0, 5, 30)
     t = mk({"a": a, "b": b}, capacity=40)
-    out = L.sort_values(t, ["a", "b"]).to_numpy()
+    out = L.sort_values(t, ["a", "b"], impl=impl).to_numpy()
     order = np.lexsort((b, a))
     np.testing.assert_array_equal(out["a"], a[order])
     np.testing.assert_array_equal(out["b"], b[order])
 
 
-def test_sort_descending(rng):
+def test_sort_descending(rng, impl=None):
     vals = rng.integers(-100, 100, 25)
     t = mk({"k": vals})
-    out = L.sort_values(t, ["k"], ascending=False).to_numpy()
+    out = L.sort_values(t, ["k"], ascending=False, impl=impl).to_numpy()
     np.testing.assert_array_equal(out["k"], np.sort(vals)[::-1])
 
 
-def test_sort_descending_float(rng):
+def test_sort_descending_float(rng, impl=None):
     vals = rng.normal(size=25).astype(np.float32)
     t = mk({"k": vals})
-    out = L.sort_values(t, ["k"], ascending=False).to_numpy()
+    out = L.sort_values(t, ["k"], ascending=False, impl=impl).to_numpy()
     np.testing.assert_allclose(out["k"], np.sort(vals)[::-1])
 
 
@@ -108,10 +108,10 @@ def test_sort_keeps_padding_at_end():
 # --------------------------------------------------------------------------
 
 
-def test_drop_duplicates(rng):
+def test_drop_duplicates(rng, impl=None):
     keys = rng.integers(0, 8, 50)
     t = mk({"k": keys, "v": np.arange(50)}, capacity=64)
-    out = L.drop_duplicates(t, ["k"]).to_numpy()
+    out = L.drop_duplicates(t, ["k"], impl=impl).to_numpy()
     assert sorted(out["k"]) == sorted(np.unique(keys))
     # keeps the FIRST occurrence of each key
     for k, v in zip(out["k"], out["v"]):
@@ -119,11 +119,11 @@ def test_drop_duplicates(rng):
         assert v == first
 
 
-def test_drop_duplicates_idempotent(rng):
+def test_drop_duplicates_idempotent(rng, impl=None):
     keys = rng.integers(0, 5, 30)
     t = mk({"k": keys})
-    once = L.drop_duplicates(t, ["k"])
-    twice = L.drop_duplicates(once, ["k"])
+    once = L.drop_duplicates(t, ["k"], impl=impl)
+    twice = L.drop_duplicates(once, ["k"], impl=impl)
     assert as_sets(once.to_numpy()) == as_sets(twice.to_numpy())
 
 
@@ -138,11 +138,12 @@ def test_drop_duplicates_multi_col():
 # --------------------------------------------------------------------------
 
 
-def test_groupby_sum_mean_count(rng):
+def test_groupby_sum_mean_count(rng, impl=None):
     keys = rng.integers(0, 6, 64)
     vals = rng.normal(size=64).astype(np.float32)
     t = mk({"k": keys, "v": vals}, capacity=80)
-    out = L.groupby_aggregate(t, ["k"], {"v": ["sum", "mean", "count"]})
+    out = L.groupby_aggregate(t, ["k"], {"v": ["sum", "mean", "count"]},
+                              impl=impl)
     o = out.to_numpy()
     for i, k in enumerate(o["k"]):
         sub = vals[keys == k]
@@ -153,11 +154,12 @@ def test_groupby_sum_mean_count(rng):
     assert int(out.nvalid) == len(np.unique(keys))
 
 
-def test_groupby_min_max(rng):
+def test_groupby_min_max(rng, impl=None):
     keys = rng.integers(0, 4, 40)
     vals = rng.normal(size=40).astype(np.float32)
     t = mk({"k": keys, "v": vals})
-    o = L.groupby_aggregate(t, ["k"], {"v": ["min", "max"]}).to_numpy()
+    o = L.groupby_aggregate(t, ["k"], {"v": ["min", "max"]},
+                            impl=impl).to_numpy()
     for i, k in enumerate(o["k"]):
         sub = vals[keys == k]
         np.testing.assert_allclose(o["v_min"][i], sub.min(), rtol=1e-6)
@@ -198,11 +200,11 @@ def test_scalar_aggregate(rng):
 # --------------------------------------------------------------------------
 
 
-def test_inner_join_matches_oracle(rng):
+def test_inner_join_matches_oracle(rng, impl=None):
     left = {"k": rng.integers(0, 10, 30), "lv": np.arange(30)}
     right = {"k": rng.integers(0, 10, 20), "rv": np.arange(20) * 10}
     lt, rt = mk(left, capacity=40), mk(right, capacity=25)
-    out = L.join(lt, rt, left_on=["k"], out_capacity=200).to_numpy()
+    out = L.join(lt, rt, left_on=["k"], out_capacity=200, impl=impl).to_numpy()
     want = np_join_inner(left, right, "k")
     assert as_sets(out) == as_sets(want)
 
@@ -373,13 +375,13 @@ def test_isin():
     assert not mask[4:].any()
 
 
-def test_intersect_and_difference(rng):
+def test_intersect_and_difference(rng, impl=None):
     a_keys = rng.integers(0, 12, 30)
     b_keys = rng.integers(0, 12, 30)
     a = mk({"k": a_keys}, capacity=40)
     b = mk({"k": b_keys}, capacity=40)
-    inter = L.intersect(a, b, ["k"]).to_numpy()["k"]
-    diff = L.difference(a, b, ["k"]).to_numpy()["k"]
+    inter = L.intersect(a, b, ["k"], impl=impl).to_numpy()["k"]
+    diff = L.difference(a, b, ["k"], impl=impl).to_numpy()["k"]
     want_inter = np.intersect1d(a_keys, b_keys)
     np.testing.assert_array_equal(np.sort(inter), want_inter)
     want_diff = a_keys[~np.isin(a_keys, b_keys)]
@@ -455,3 +457,29 @@ def test_lex_searchsorted_two_keys():
     hi = L.lex_searchsorted((a, b), (jnp.array([2]), jnp.array([1])),
                             side="right")
     assert int(lo[0]) == 2 and int(hi[0]) == 3
+
+
+# --------------------------------------------------------------------------
+# the tests above that run a family's default algorithm, on the family's
+# other backend (which runs only when a caller names it)
+# --------------------------------------------------------------------------
+
+OTHER_BACKEND = [
+    (test_sort_single_key, "radix"),
+    (test_sort_is_stable, "radix"),
+    (test_sort_multi_key_matches_lexsort, "radix"),
+    (test_sort_descending, "radix"),
+    (test_sort_descending_float, "radix"),
+    (test_drop_duplicates, "hash"),
+    (test_drop_duplicates_idempotent, "hash"),
+    (test_groupby_sum_mean_count, "hash"),
+    (test_groupby_min_max, "hash"),
+    (test_inner_join_matches_oracle, "hash"),
+    (test_intersect_and_difference, "hash"),
+]
+
+
+@pytest.mark.parametrize("test, impl", OTHER_BACKEND,
+                         ids=[t.__name__[5:] for t, _ in OTHER_BACKEND])
+def test_on_other_backend(test, impl, rng):
+    test(rng, impl=impl)

@@ -176,16 +176,27 @@ def test_join_keys_around_2_31_no_false_matches(ctx1):
 # --------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("world", [1, 2, 4])
-def test_morsel_conformance(world):
+def _morsel_conformance(world, backend):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={world}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     proc = subprocess.run(
-        [sys.executable,
-         os.path.join(HERE, "dist", "morsel_conformance.py"), str(world)],
+        [sys.executable, os.path.join(HERE, "dist", "morsel_conformance.py"),
+         str(world), backend],
         env=env, capture_output=True, text=True, timeout=1800)
     sys.stdout.write(proc.stdout[-4000:])
     sys.stderr.write(proc.stderr[-4000:])
-    assert proc.returncode == 0, f"morsel conformance failed (world={world})"
+    assert proc.returncode == 0, \
+        f"morsel conformance failed (world={world}, {backend})"
     assert "MORSEL CONFORMANCE PASSED" in proc.stdout
+
+
+@pytest.mark.parametrize("world", [1, 2, 4])
+def test_morsel_conformance(world):
+    _morsel_conformance(world, "default")
+
+
+@pytest.mark.parametrize("world", [1, 2, 4])
+def test_morsel_conformance_hash_backends(world):
+    """Hash join and groupby, radix sort: reached only by argument."""
+    _morsel_conformance(world, "hash")

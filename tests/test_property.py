@@ -43,9 +43,8 @@ def test_nvalid_never_exceeds_capacity(t):
     assert int(t.nvalid) <= t.capacity
 
 
-@given(tables())
-def test_sort_is_permutation_and_ordered(t):
-    out = L.sort_values(t, ["k"])
+def sort_law(t, impl=None):
+    out = L.sort_values(t, ["k"], impl=impl)
     assert int(out.nvalid) == int(t.nvalid)
     got = out.to_numpy()
     want = t.to_numpy()
@@ -55,12 +54,56 @@ def test_sort_is_permutation_and_ordered(t):
     assert as_sets(got) == as_sets(want)
 
 
-@given(tables())
-def test_dedup_subset_of_input_and_unique(t):
-    out = L.drop_duplicates(t, ["k"]).to_numpy()
+def dedup_law(t, impl=None):
+    out = L.drop_duplicates(t, ["k"], impl=impl).to_numpy()
     keys = t.to_numpy()["k"]
     assert set(out["k"]) == set(keys)
     assert len(out["k"]) == len(np.unique(keys))
+
+
+def groupby_total_law(t, impl=None):
+    out = L.groupby_aggregate(t, ["k"], {"v": "sum"}, impl=impl)
+    total_groups = float(L.aggregate(out, "v_sum", "sum"))
+    total_rows = float(L.aggregate(t, "v", "sum"))
+    np.testing.assert_allclose(total_groups, total_rows, rtol=1e-4,
+                               atol=1e-4)
+
+
+def join_count_law(a, b, impl=None):
+    na = a.to_numpy()["k"]
+    nb = b.to_numpy()["k"]
+    want = sum(int((na == k).sum()) * int((nb == k).sum())
+               for k in np.unique(na))
+    out, overflow = L.join(a, b, left_on=["k"], out_capacity=1024,
+                           return_overflow=True, impl=impl)
+    assert int(out.nvalid) == want
+    assert int(overflow) == 0
+
+
+def partition_law(a, b, impl=None, dedup_impl=None):
+    """difference(a,b) ∪ semijoin(a,b) == a (as key sets)."""
+    inter = set(L.intersect(a, b, ["k"], impl=impl,
+                            dedup_impl=dedup_impl).to_numpy()["k"])
+    diff = set(L.difference(a, b, ["k"], impl=impl).to_numpy()["k"])
+    keys = set(a.to_numpy()["k"])
+    assert inter | diff == keys
+    assert inter & diff == set()
+
+
+def union_self_law(t, impl=None):
+    u = L.union(t, t, impl=impl).to_numpy()
+    d = L.drop_duplicates(t, impl=impl).to_numpy()
+    assert as_sets(u) == as_sets(d)
+
+
+@given(tables())
+def test_sort_is_permutation_and_ordered(t):
+    sort_law(t)
+
+
+@given(tables())
+def test_dedup_subset_of_input_and_unique(t):
+    dedup_law(t)
 
 
 @given(tables(), st.integers(0, 7))
@@ -74,11 +117,7 @@ def test_select_conjunction_composes(t, cut):
 
 @given(tables())
 def test_groupby_sum_preserves_total(t):
-    out = L.groupby_aggregate(t, ["k"], {"v": "sum"})
-    total_groups = float(L.aggregate(out, "v_sum", "sum"))
-    total_rows = float(L.aggregate(t, "v", "sum"))
-    np.testing.assert_allclose(total_groups, total_rows, rtol=1e-4,
-                               atol=1e-4)
+    groupby_total_law(t)
 
 
 @st.composite
@@ -170,31 +209,35 @@ def test_dedup_backends_bit_identical(t):
 
 @given(tables(), tables())
 def test_join_row_count_is_sum_of_key_products(a, b):
-    na = a.to_numpy()["k"]
-    nb = b.to_numpy()["k"]
-    want = sum(int((na == k).sum()) * int((nb == k).sum())
-               for k in np.unique(na))
-    out, overflow = L.join(a, b, left_on=["k"], out_capacity=1024,
-                           return_overflow=True)
-    assert int(out.nvalid) == want
-    assert int(overflow) == 0
+    join_count_law(a, b)
 
 
 @given(tables(), tables())
 def test_intersect_difference_partition_left(a, b):
-    """difference(a,b) ∪ semijoin(a,b) == a (as key sets)."""
-    inter = set(L.intersect(a, b, ["k"]).to_numpy()["k"])
-    diff = set(L.difference(a, b, ["k"]).to_numpy()["k"])
-    keys = set(a.to_numpy()["k"])
-    assert inter | diff == keys
-    assert inter & diff == set()
+    partition_law(a, b)
 
 
 @given(tables())
 def test_union_with_self_is_dedup(t):
-    u = L.union(t, t).to_numpy()
-    d = L.drop_duplicates(t).to_numpy()
-    assert as_sets(u) == as_sets(d)
+    union_self_law(t)
+
+
+# The laws above, on the backend of each family that runs only when a
+# caller names it (the tests above run the defaults).
+OTHER_BACKEND_LAWS = {
+    "sort_radix": lambda a, b: sort_law(a, "radix"),
+    "dedup_hash": lambda a, b: dedup_law(a, "hash"),
+    "groupby_total_hash": lambda a, b: groupby_total_law(a, "hash"),
+    "join_count_hash": lambda a, b: join_count_law(a, b, "hash"),
+    "partition_hash": lambda a, b: partition_law(a, b, "hash", "hash"),
+    "union_self_hash": lambda a, b: union_self_law(a, "hash"),
+}
+
+
+@pytest.mark.parametrize("law", sorted(OTHER_BACKEND_LAWS))
+@given(a=tables(), b=tables())
+def test_law_on_other_backend(law, a, b):
+    OTHER_BACKEND_LAWS[law](a, b)
 
 
 @given(mixed_key_tables(), mixed_key_tables())

@@ -20,7 +20,7 @@ import sys
 import numpy as np
 import pytest
 
-from repro.core import kernel_backend, local_ops as L
+from repro.core import local_ops as L
 from repro.core.table import Table
 
 from oracles import np_difference, np_intersect, np_isin, np_union
@@ -258,25 +258,21 @@ def test_hash_path_contains_no_sort_primitive(capacity, rng):
         lambda x, y: L.intersect(x, y, on=["k"], impl="hash",
                                  dedup_impl="hash"), ta, tb)
     assert "sort" not in prims, sorted(prims)
-    # the sortmerge backend, for contrast, does sort — unless the radix
-    # engine is the session default, which makes even that path sort-free
+    # the sortmerge backend, for contrast, does sort
     prims = _jaxpr_primitives(
         lambda x, y: L.isin(x, "k", y, "k", impl="sortmerge"), ta, tb)
-    if kernel_backend.sort_impl() == "xla":
-        assert "sort" in prims
-    else:
-        assert "sort" not in prims, sorted(prims)
+    assert "sort" in prims
 
 
-def test_env_default_backend(monkeypatch, rng):
+def test_env_default_backend(rng):
     a, b = make_pair("uniform", rng)
     ta, tb = tables(a, b)
-    monkeypatch.setenv("REPRO_SEMI_IMPL", "hash")
-    assert kernel_backend.semi_impl() == "hash"
-    mh = np.asarray(L.isin(ta, "k", tb, "k"))
-    monkeypatch.setenv("REPRO_SEMI_IMPL", "sortmerge")
-    ms = np.asarray(L.isin(ta, "k", tb, "k"))
-    np.testing.assert_array_equal(ms, mh, err_msg="env dispatch")
+    assert L.SEMI_IMPL == "sortmerge"
+    default = np.asarray(L.isin(ta, "k", tb, "k"))
+    for impl in ("sortmerge", "hash"):
+        np.testing.assert_array_equal(
+            np.asarray(L.isin(ta, "k", tb, "k", impl=impl)), default,
+            err_msg=f"default vs {impl}")
     with pytest.raises(ValueError):
         L.isin(ta, "k", tb, "k", impl="nope")
     with pytest.raises(ValueError):

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import kernel_backend, local_ops as L
+from repro.core import local_ops as L
 from repro.core.table import Table
 
 from oracles import np_sort_values
@@ -165,41 +165,16 @@ def test_compact_matches_stable_argsort_reference(rng):
     assert_bit_identical(want, got, "compact")
 
 
-def test_env_default_backend(monkeypatch, rng):
+def test_env_default_backend(rng):
     data = make_data("uniform", rng)
     t = Table.from_dict(data, capacity=ROWS)
-    monkeypatch.setenv("REPRO_SORT_IMPL", "radix")
-    assert kernel_backend.sort_impl() == "radix"
-    r = L.sort_values(t, ["k"])
-    monkeypatch.setenv("REPRO_SORT_IMPL", "xla")
-    x = L.sort_values(t, ["k"])
-    assert_bit_identical(x, r, "env dispatch")
+    assert L.SORT_IMPL == "xla"
+    default = L.sort_values(t, ["k"])
+    for impl in ("xla", "radix"):
+        assert_bit_identical(default, L.sort_values(t, ["k"], impl=impl),
+                             f"default vs {impl}")
     with pytest.raises(ValueError):
         L.sort_values(t, ["k"], impl="nope")
-
-
-def test_sort_feeds_sort_based_operators(monkeypatch, rng):
-    """Operators built on sort_values (dedup, groupby, sortmerge join)
-    are backend-invariant end to end."""
-    data = make_data("skewed", rng)
-    t = Table.from_dict(data, capacity=ROWS + 3)
-    outs = {}
-    for impl in ("xla", "radix"):
-        monkeypatch.setenv("REPRO_SORT_IMPL", impl)
-        d = L.drop_duplicates(t, ["k"], impl="sort")
-        g = L.groupby_aggregate(t, ["k"], {"v": ["sum", "count"]},
-                                impl="sort")
-        j = L.join(t, t, left_on=["k"], how="inner",
-                   out_capacity=ROWS * ROWS, impl="sortmerge")
-        outs[impl] = (d, g, j)
-    for a, b in zip(outs["xla"], outs["radix"]):
-        assert int(a.nvalid) == int(b.nvalid)
-        for c in a.names:
-            np.testing.assert_array_equal(
-                np.nan_to_num(np.asarray(a.columns[c])[:int(a.nvalid)],
-                              nan=-1e9),
-                np.nan_to_num(np.asarray(b.columns[c])[:int(b.nvalid)],
-                              nan=-1e9), err_msg=c)
 
 
 @pytest.mark.parametrize("world", [1, 2, 4])
